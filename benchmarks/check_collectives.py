@@ -49,12 +49,13 @@ def _child() -> dict:
 
     from repro.configs import base as cb
     from repro.core.policy import DEFAULT_POLICY
+    from repro.distributed.sharding import make_mesh
     from repro.engine import compile_plan
     from repro.models import transformer as T
     from repro.obs.collectives import audit_engine
     from repro.serve.engine import ServeEngine
 
-    mesh = jax.make_mesh(MESH_SHAPE, MESH_AXES)
+    mesh = make_mesh(MESH_SHAPE, MESH_AXES)
     cfg = cb.get_config(ARCH, smoke=True)
     params = T.init_lm(cfg, jax.random.key(0))
     out = {}

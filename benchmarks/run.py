@@ -18,6 +18,9 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (ensemble_bench, fig23_curves, kernel_bench,
                             plan_bench, roofline_report, serve_bench, table1,
                             xnor_bench, xnor_conv_bench)

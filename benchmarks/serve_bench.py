@@ -186,11 +186,12 @@ def _sharded_child(modes: list[str], n: int, cap: int, slots: int,
     from benchmarks.common import run_manifest
     from repro.configs import base as cb
     from repro.core.policy import DEFAULT_POLICY
+    from repro.distributed.sharding import make_mesh
     from repro.engine import compile_plan
     from repro.models import transformer as T
     from repro.serve.engine import ServeEngine
 
-    mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     cfg = cb.get_config(ARCH, smoke=True)
     if widen != 1:
         cfg = dataclasses.replace(cfg, d_model=cfg.d_model * widen,

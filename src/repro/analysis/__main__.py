@@ -84,12 +84,13 @@ def _live_child(mode: str) -> List[Finding]:
     from repro.analysis.retrace import RetraceSentinel
     from repro.configs import base as cb
     from repro.core.policy import DEFAULT_POLICY
+    from repro.distributed.sharding import make_mesh
     from repro.engine import compile_plan
     from repro.models import transformer as T
     from repro.serve.batcher import SlotBatcher
     from repro.serve.engine import ServeEngine, stream_serve
 
-    mesh = jax.make_mesh(_MESH_SHAPE, _MESH_AXES)
+    mesh = make_mesh(_MESH_SHAPE, _MESH_AXES)
     axis_sizes = dict(zip(_MESH_AXES, _MESH_SHAPE))
     cfg = cb.get_config(_ARCH, smoke=True)
     params = T.init_lm(cfg, jax.random.key(0))

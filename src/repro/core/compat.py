@@ -1,15 +1,8 @@
-"""Small shared helpers spanning jax API renames + backend dispatch.
-
-Kept in one place so the next jax rename is a one-file fix (both kernel
-packages — ``repro.kernels`` and ``repro.xnor`` — import from here).
-"""
+"""Small shared helpers of both kernel packages (``repro.kernels`` and
+``repro.xnor``): backend dispatch and block rounding."""
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def on_tpu() -> bool:

@@ -21,11 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental around 0.6; support both.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def pipeline_forward(
     stage_fn: Callable,          # (stage_params, x) -> y  (same shape)
@@ -82,15 +77,11 @@ def run_pipeline(mesh: Mesh, stage_fn: Callable, stage_params, micro,
     n_stages = mesh.shape[axis_name]
     fwd = pipeline_forward(stage_fn, n_stages, axis_name)
     pspec_params = jax.tree.map(lambda _: P(axis_name), stage_params)
-    import inspect
-    sig = inspect.signature(_shard_map).parameters
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    check_kw = {"check_vma": False} if "check_vma" in sig else {"check_rep": False}
-    out = _shard_map(
+    out = jax.shard_map(
         fwd, mesh=mesh,
         in_specs=(pspec_params, P()),
         out_specs=P(axis_name),   # (stage, n_micro, mb, ...): last stage valid
-        **check_kw,
+        check_vma=False,
     )(stage_params, micro)
     # out has a leading stage axis from out_specs; take the last stage's copy
     n_micro = micro.shape[0]

@@ -32,7 +32,7 @@ import re
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def batch_axes(mesh: Optional[Mesh]) -> tuple:
@@ -41,15 +41,24 @@ def batch_axes(mesh: Optional[Mesh]) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def mesh_context(mesh: Mesh):
-    """Context manager activating ``mesh`` as the ambient mesh.
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """Builds a device mesh with ``Auto`` axes — the one constructor every
+    mesh in this repo goes through.
 
-    Spans the jax API change: ``jax.set_mesh`` (jax >= 0.5-era) vs entering
-    the ``Mesh`` object itself (jax 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which a sharding
+    is part of an array's type and ops such as the embedding gather must
+    name their output sharding. The model code places arrays and pins
+    activations (``ShardCtx``) and lets GSPMD propagate the rest, which is
+    what ``Auto`` axes do."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def mesh_context(mesh: Mesh):
+    """Context manager activating ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 @dataclasses.dataclass
@@ -340,9 +349,32 @@ def _place_serving_node(mesh: Mesh, spec: P, node, types=None):
         # generic over any registered pytree node class: place each stored
         # array, keep the node's static aux data
         kids, treedef = jax.tree_util.tree_flatten(node)
-        return jax.tree_util.tree_unflatten(
-            treedef, [put(a) for a in kids])
+        return with_model_split(jax.tree_util.tree_unflatten(
+            treedef, [put(a) for a in kids]), spec)
     return put(node)
+
+
+def model_split(spec: P) -> Optional[str]:
+    """Which dim of a (..., K, N) master-shape projection spec is split over
+    "model": "n" (out-channel), "k" (contraction) or None."""
+    def on_model(e):
+        return e == "model" or (isinstance(e, (tuple, list)) and "model" in e)
+
+    entries = list(spec)
+    if entries and on_model(entries[-1]):
+        return "n"
+    if len(entries) >= 2 and on_model(entries[-2]):
+        return "k"
+    return None
+
+
+def with_model_split(node, spec: P):
+    """``node`` recording its placement split (:func:`model_split`), for
+    serving leaves that carry one (a static ``tp`` field): their kernels
+    run per device under shard_map on a mesh (``engine.backends``)."""
+    if dataclasses.is_dataclass(node) and hasattr(node, "tp"):
+        return dataclasses.replace(node, tp=model_split(spec))
+    return node
 
 
 def place_packed_params(mesh: Mesh, params, plan=None):

@@ -103,6 +103,13 @@ def _packed_conv_eligible(lc: LeafContext) -> tuple[bool, str]:
 # pack transforms (bit-identical to the legacy pack_params monolith)
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=1)
+def _abs_mean(w, axis):
+    """f32 mean |w| over ``axis`` (the BWN alpha). One jit, so a bf16
+    master is never copied to f32 in full."""
+    return jnp.mean(jnp.abs(w.astype(jnp.float32)), axis=axis)
+
+
 def _pack_dense(lc: LeafContext, leaf, pc: PackContext):
     return leaf
 
@@ -130,7 +137,7 @@ def _pack_binarized_dense(lc: LeafContext, leaf, pc: PackContext):
     inference network for conv layers with no bitpacked lowering."""
     scale = None
     if pc.with_scale:
-        scale = jnp.mean(jnp.abs(leaf.astype(jnp.float32)), axis=(0, 1, 2))
+        scale = _abs_mean(leaf, (0, 1, 2))
     wb = _binarize_values(lc, leaf, pc)
     if scale is not None:
         wb = (wb.astype(jnp.float32) * scale).astype(leaf.dtype)
@@ -159,7 +166,7 @@ def _pack_linear(cls, lc: LeafContext, leaf, pc: PackContext):
             lambda w: kops.binarize_and_pack(w, stochastic=False))(w2)
     scale = None
     if pc.with_scale:
-        scale = jnp.mean(jnp.abs(w2.astype(jnp.float32)), axis=1)  # (-1, N)
+        scale = _abs_mean(w2, 1)  # (-1, N)
         scale = scale.reshape(lead + (n_dim,))
     packed = packed.reshape(lead + (k_dim // PACK, n_dim))
     return cls(packed, scale, k_dim)
@@ -177,7 +184,7 @@ def _pack_packed_conv(lc: LeafContext, leaf, pc: PackContext):
     kh, kw, c_in, n_dim = leaf.shape
     scale = None
     if pc.with_scale:
-        scale = jnp.mean(jnp.abs(leaf.astype(jnp.float32)), axis=(0, 1, 2))
+        scale = _abs_mean(leaf, (0, 1, 2))
     w2 = leaf.reshape((kh * kw * c_in, n_dim))
     packed = kops.binarize_and_pack(
         w2, jax.random.fold_in(pc.key, lc.index), stochastic=True)
@@ -189,7 +196,7 @@ def _pack_xnor_conv(lc: LeafContext, leaf, pc: PackContext):
 
     scale = None
     if pc.with_scale:
-        scale = jnp.mean(jnp.abs(leaf.astype(jnp.float32)), axis=(0, 1, 2))
+        scale = _abs_mean(leaf, (0, 1, 2))
     kh, kw, c_in, _ = leaf.shape
     return XnorConv(pack_conv_kernel(leaf), scale, (kh, kw), c_in)
 
@@ -206,17 +213,84 @@ def _apply_dense(w, x, *, stride=None, padding=None):
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
+def _ambient_mesh():
+    """The mesh of ``jax.set_mesh`` when it spans several devices, else
+    None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _per_device(run, w, x):
+    """``run(x, packed, scale)`` — a Pallas kernel — under the ambient mesh.
+
+    The TPU compiler cannot partition a Pallas kernel, so on a mesh of
+    several devices it runs once per device under ``shard_map``, on the
+    weight slice placement gave that device (``w.tp``): the local
+    out-channels for "n", the local contraction words for "k" (``run``
+    then reduces over "model" itself), everything for None. The leading
+    (batch) dim of ``x`` stays split over the data axes where it divides.
+    Each output element is computed exactly as on one device."""
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return run(x, w.packed, w.scale)
+    from jax.sharding import PartitionSpec as P
+
+    split = w.tp if "model" in mesh.axis_names else None
+    k_ax = "model" if split == "k" else None
+    n_ax = "model" if split == "n" else None
+    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    n_dp = 1
+    for a in dp:
+        n_dp *= mesh.shape[a]
+    batch = None
+    if dp and x.ndim > 1 and x.shape[0] % n_dp == 0:
+        batch = dp if len(dp) > 1 else dp[0]
+    lead = (batch,) + (None,) * (x.ndim - 2) if x.ndim > 1 else ()
+    specs = [P(*lead, k_ax),
+             P(*(None,) * (w.packed.ndim - 2), k_ax, n_ax)]
+    args = [x, w.packed]
+    if w.scale is not None:
+        specs.append(P(*(None,) * (w.scale.ndim - 1), n_ax))
+        args.append(w.scale)
+    return jax.shard_map(
+        lambda x, packed, scale=None: run(x, packed, scale), mesh=None,
+        in_specs=tuple(specs), out_specs=P(*lead, n_ax),
+        check_vma=False)(*args)
+
+
 def _apply_packed(w: PackedLinear, x):
     from repro.kernels import ops
 
-    out = ops.binary_matmul(x, w.packed, w.scale, out_dtype=jnp.float32)
-    return out.astype(x.dtype)
+    def run(x, packed, scale):
+        return ops.binary_matmul(x, packed, scale, out_dtype=jnp.float32)
+
+    return _per_device(run, w, x).astype(x.dtype)
 
 
 def _apply_xnor(w: XnorLinear, x):
     from repro.xnor import ops as xops
 
-    out = xops.xnor_matmul(x, w.packed, w.scale, k=w.k, out_dtype=jnp.float32)
+    if w.tp != "k" or _ambient_mesh() is None:
+        def run(x, packed, scale):
+            return xops.xnor_matmul(x, packed, scale, k=w.k,
+                                    out_dtype=jnp.float32)
+
+        return _per_device(run, w, x).astype(x.dtype)
+
+    # Row-parallel: each device dots its contraction words; the int32
+    # partial dots sum exactly over "model". Zero-padding x to whole words
+    # adds 0-bit pairs, each +1 in the summed dot, taken off after.
+    from repro.xnor.packing import pad_features
+
+    x = pad_features(x)
+
+    def run(x, packed, scale):
+        dot = xops.xnor_matmul(x, packed, k=x.shape[-1], out_dtype=jnp.int32)
+        return jax.lax.psum(dot, "model")
+
+    out = (_per_device(run, w, x) - (x.shape[-1] - w.k)).astype(jnp.float32)
+    if w.scale is not None:
+        out = out * w.scale.astype(jnp.float32)
     return out.astype(x.dtype)
 
 

@@ -21,6 +21,8 @@ import math
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.distributed.sharding import make_mesh
+
 
 def best_mesh_shape(n_devices: int, model_parallel: int,
                     axis_names=("data", "model")) -> tuple[int, ...]:
@@ -34,9 +36,7 @@ def best_mesh_shape(n_devices: int, model_parallel: int,
 def make_elastic_mesh(model_parallel: int, devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     shape = best_mesh_shape(len(devices), model_parallel)
-    import numpy as np
-
-    return Mesh(np.asarray(devices).reshape(shape), ("data", "model"))
+    return make_mesh(shape, ("data", "model"), devices=devices)
 
 
 def reshard(tree, pspecs, mesh: Mesh):

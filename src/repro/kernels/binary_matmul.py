@@ -27,16 +27,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import CompilerParams as _CompilerParams
 from repro.core.packing import PACK
 
 
 def _unpack_block(words: jax.Array, bk: int, dtype) -> jax.Array:
-    """(bk//32, bn) int32 -> (bk, bn) ±1 in ``dtype`` (VMEM-local)."""
-    w = words.astype(jnp.uint32)
-    shifts = jnp.arange(PACK, dtype=jnp.uint32)[None, :, None]
-    bits = (w[:, None, :] >> shifts) & jnp.uint32(1)
-    pm1 = 2.0 * bits.astype(jnp.float32) - 1.0
+    """(bk//32, bn) int32 -> (bk, bn) ±1 in ``dtype`` (VMEM-local).
+
+    Bits are extracted in int32 (the TPU has no uint32 -> float cast); the
+    sign fill of the arithmetic shift is masked off by ``& 1``."""
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, PACK, 1), 1)
+    bits = (words[:, None, :] >> shifts) & 1
+    pm1 = (2 * bits - 1).astype(jnp.float32)
     return pm1.reshape(bk, words.shape[-1]).astype(dtype)
 
 
@@ -130,7 +131,7 @@ def binary_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(*args)

@@ -111,11 +111,9 @@ def binarize_and_pack(
 ) -> jax.Array:
     """Fused binarize (Eq. 1 or 2) + bitpack of a (K, N) master weight.
 
-    Returns (ceil(K/32), N) int32. Off-TPU the stochastic path draws its
-    uniform words with ``jax.random.bits`` (interpret mode cannot lower the
-    TPU PRNG); on TPU the same operand path is used for determinism across
-    backends — the in-kernel PRNG variant is available via
-    ``stoch_binarize.binarize_pack_pallas(use_tpu_prng=True)``.
+    Returns (ceil(K/32), N) int32. The stochastic path draws its uniform
+    words with ``jax.random.bits``, so a key packs the same replica on
+    every backend.
     """
     kdim, n = w.shape
     wp = pad_to_pack(w, axis=0)

@@ -1,14 +1,11 @@
 """Pallas TPU kernel: fused (stochastic|deterministic) binarize + bitpack.
 
 FPGA stochastic BNNs use on-fabric LFSRs to draw the Bernoulli samples of
-Eq. (2); the TPU analogue is the on-chip PRNG (``pltpu.prng_random_bits``).
-The CPU Pallas interpreter has no lowering for the TPU PRNG primitives, so
-the kernel is written to take the uniform random words as an *operand*
-(``bits``): on a real TPU the caller can cheaply generate them with
-``pltpu.prng_random_bits`` (the ``use_tpu_prng`` flag swaps the body), while
-in interpret mode / tests they come from ``jax.random.bits``. The kernel body
-— threshold against hard_sigmoid(w) in fixed point, pack 32 lanes into one
-int32 word — is identical in both paths and is what tests validate.
+Eq. (2). Here the uniform random words are an *operand* (``bits``, drawn by
+the caller with ``jax.random.bits``), so the packed replica depends only on
+the key and is the same on every backend. The kernel body thresholds them
+against hard_sigmoid(w) in fixed point and packs 32 rows into one int32
+word.
 
 Layout: w     (K, N) f32/bf16 master weights
         bits  (K, N) uint32 uniform random words (stochastic only)
@@ -21,6 +18,10 @@ clipping produces) must yield bit 1 for *every* random word, but the f32
 comparison alone cannot guarantee it — words >= 2^32 - 128 round up to
 2^32.0f and tie with the threshold — so the kernels force the p >= 1 lane
 explicitly. p = 0 (w <= -1) is exact as-is (u < 0 never holds).
+
+The TPU vector unit has no unsigned reductions and no uint32 -> float cast,
+so the kernel works in int32: the random words are bitcast to int32 before
+the call, and packing sums disjoint bits in int32.
 """
 from __future__ import annotations
 
@@ -29,7 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PACK
 
@@ -37,39 +37,39 @@ _TWO32 = 4294967296.0  # 2 ** 32
 
 
 def _pack_block(ones: jax.Array, bk: int) -> jax.Array:
-    """(bk, bn) uint32 {0,1} -> (bk//32, bn) int32 packed words."""
+    """(bk, bn) int32 {0,1} -> (bk//32, bn) int32 packed words.
+
+    The 32 shifted bits of a word are disjoint, so their int32 sum is their
+    OR: no carry ever happens, and bit 31 lands on the sign bit."""
     bn = ones.shape[-1]
     b = ones.reshape(bk // PACK, PACK, bn)
-    shifts = jnp.arange(PACK, dtype=jnp.uint32)[None, :, None]
-    words = jnp.sum(b << shifts, axis=1, dtype=jnp.uint32)
-    return words.astype(jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, PACK, 1), 1)
+    return jnp.sum(b << shifts, axis=1)
+
+
+def _word_to_f32(x: jax.Array) -> jax.Array:
+    """A uint32 word held bitcast in int32 -> its value as f32, rounded like
+    a uint32 -> f32 cast: both 16-bit halves are exact in f32, so their sum
+    rounds once, to nearest."""
+    hi = ((x >> 16) & 0xFFFF).astype(jnp.float32)
+    lo = (x & 0xFFFF).astype(jnp.float32)
+    return hi * 65536.0 + lo
 
 
 def _stoch_kernel(w_ref, bits_ref, o_ref, *, bk: int):
     w = w_ref[...].astype(jnp.float32)
     p = jnp.clip((w + 1.0) * 0.5, 0.0, 1.0)            # Eq. (3)
     thresh = (p * _TWO32).astype(jnp.float32)
-    u = bits_ref[...].astype(jnp.float32)               # uniform in [0, 2^32)
+    u = _word_to_f32(bits_ref[...])                     # uniform in [0, 2^32)
     # p >= 1 forced: u rounds to 2^32.0f for the top 128 words and would
     # tie with the threshold, turning a sure bit into a 3e-8 miss
-    ones = ((u < thresh) | (p >= 1.0)).astype(jnp.uint32)  # P(one) = p (Eq. 2)
-    o_ref[...] = _pack_block(ones, bk)
-
-
-def _stoch_kernel_tpu_prng(seed_ref, w_ref, o_ref, *, bk: int):
-    """Real-TPU variant: draws bits on chip. Not lowerable on CPU interpret."""
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0), pl.program_id(1))
-    w = w_ref[...].astype(jnp.float32)
-    p = jnp.clip((w + 1.0) * 0.5, 0.0, 1.0)
-    thresh = (p * _TWO32).astype(jnp.float32)
-    raw = pltpu.prng_random_bits(w.shape)
-    u = raw.astype(jnp.uint32).astype(jnp.float32)
-    ones = ((u < thresh) | (p >= 1.0)).astype(jnp.uint32)
+    ones = ((u < thresh) | (p >= 1.0)).astype(jnp.int32)  # P(one) = p (Eq. 2)
     o_ref[...] = _pack_block(ones, bk)
 
 
 def _det_kernel(w_ref, o_ref, *, bk: int):
-    ones = (w_ref[...] > 0).astype(jnp.uint32)          # Eq. (1)
+    # compared in f32: the v5e vector unit has no bf16 compare
+    ones = (w_ref[...].astype(jnp.float32) > 0).astype(jnp.int32)  # Eq. (1)
     o_ref[...] = _pack_block(ones, bk)
 
 
@@ -80,8 +80,6 @@ def binarize_pack_pallas(
     stochastic: bool,
     block_k: int = 256,
     block_n: int = 256,
-    seed: jax.Array | None = None,
-    use_tpu_prng: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused binarize+pack. ``w`` is (K, N) with K % block_k == 0,
@@ -101,22 +99,11 @@ def binarize_pack_pallas(
             out_shape=out_shape, interpret=interpret,
         )(w)
 
-    if use_tpu_prng:
-        if seed is None:
-            raise ValueError("use_tpu_prng requires a seed scalar")
-        return pl.pallas_call(
-            functools.partial(_stoch_kernel_tpu_prng, bk=block_k),
-            grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), w_spec],
-            out_specs=o_spec,
-            out_shape=out_shape, interpret=interpret,
-        )(seed.reshape(1).astype(jnp.int32), w)
-
     if bits is None:
-        raise ValueError("stochastic=True without use_tpu_prng requires bits")
+        raise ValueError("stochastic=True requires bits")
     bits_spec = pl.BlockSpec((block_k, block_n), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_stoch_kernel, bk=block_k),
         grid=grid, in_specs=[w_spec, bits_spec], out_specs=o_spec,
         out_shape=out_shape, interpret=interpret,
-    )(w, bits)
+    )(w, jax.lax.bitcast_convert_type(bits, jnp.int32))

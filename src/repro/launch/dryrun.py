@@ -182,8 +182,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, binarize_mode: str,
     mem["peak_gb"] = (mem["argument_gb"] + mem["output_gb"] + mem["temp_gb"]
                       - mem["alias_gb"])
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<0.5 returns a per-device list
-        ca = ca[0] if ca else {}
     cost = H.analyze(compiled.as_text())
     terms = R.from_hlo_cost(cost, n_chips, model_flops=model_flops,
                             hbm_bytes_per_device=mem["peak_gb"] * 1e9)

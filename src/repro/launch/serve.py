@@ -61,8 +61,10 @@ import numpy as np
 
 from repro.configs import base as cb
 from repro.core.policy import DEFAULT_POLICY
+from repro.distributed.sharding import make_mesh
 from repro.engine import (ExecutionPlan, compile_plan, format_plan_table,
                           plan_report)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve.batcher import SlotBatcher
 from repro.serve.engine import ServeEngine, packed_param_bytes, stream_serve
@@ -93,14 +95,12 @@ def make_serve_mesh(args):
         raise SystemExit(f"--mesh has {len(axes)} axes but --mesh-shape "
                          f"has {len(shape)} entries")
     try:
-        # AttributeError: jax < 0.4.35 has no jax.make_mesh
-        mesh = jax.make_mesh(shape, axes)
-    except (ValueError, AssertionError, AttributeError) as e:
+        mesh = make_mesh(shape, axes)
+    except (ValueError, AssertionError) as e:
         raise SystemExit(
             f"cannot build mesh {dict(zip(axes, shape))} over "
             f"{jax.device_count()} visible device(s): {e} — on CPU, set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N (and "
-            f"jax >= 0.4.35 for jax.make_mesh)") from None
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=N") from None
     print(f"mesh: {dict(zip(axes, shape))} over {mesh.devices.size} devices")
     return mesh
 
@@ -160,8 +160,9 @@ def make_plan(params, policy, args, mesh=None) -> ExecutionPlan:
     return plan
 
 
-def serve_classifier(arch: str, args) -> None:
-    """Fixed-batch image-classification serving for the paper's nets."""
+def serve_classifier(arch: str, args) -> dict:
+    """Fixed-batch image-classification serving for the paper's nets.
+    Returns the summary :func:`serve` documents."""
     from repro.data import synthetic as syn
     from repro.launch.train import make_paper_policy
     from repro.models import mnist_fc, vgg
@@ -242,7 +243,9 @@ def serve_classifier(arch: str, args) -> None:
         t1 = time.perf_counter()
         take = min(args.slots, args.requests - done)
         if ensemble_set is not None:
+            fwd_args = (x,)
             es = fwd(x)
+            logits = es.mean_logits
             preds = jax.numpy.argmax(es.mean_logits, axis=-1)
             jax.block_until_ready(preds)
             agr = np.asarray(es.agreement)[:take]   # drop ragged-batch pad
@@ -250,7 +253,9 @@ def serve_classifier(arch: str, args) -> None:
             if args.abstain_threshold is not None:
                 n_abstained += int((agr < args.abstain_threshold).sum())
         else:
-            preds = jax.numpy.argmax(fwd(params, mstate, x), axis=-1)
+            fwd_args = (params, mstate, x)
+            logits = fwd(params, mstate, x)
+            preds = jax.numpy.argmax(logits, axis=-1)
             jax.block_until_ready(preds)
         lat.append(time.perf_counter() - t1)
         done += take
@@ -298,9 +303,14 @@ def serve_classifier(arch: str, args) -> None:
                                     "docs/ANALYSIS.md):"))
         if gate(analysis_findings):
             raise SystemExit(1)
+    return {"arch": arch, "plan": plan.mode if args.packed else "dense",
+            "requests": done, "seconds": dt, "forward": fwd,
+            "forward_args": fwd_args, "logits": logits}
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's options; ``serve(build_parser().parse_args(argv))``
+    runs the same path in-process."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -391,8 +401,17 @@ def main() -> None:
                          "archs), and the retrace sentinel over the "
                          "serving loop — exits nonzero on error findings "
                          "(docs/ANALYSIS.md)")
-    args = ap.parse_args()
+    return ap
 
+
+def serve(args) -> dict:
+    """Serves ``args`` (from :func:`build_parser`) and returns a summary:
+    ``arch``, ``plan`` (the packed plan's mode, or "dense"), ``requests``
+    completed and wall ``seconds``. Token archs add ``tokens``, ``steps``,
+    the ``engine``, the ``completed`` requests and the ``prefix_cache``
+    stats (None without ``--prefix-cache``); classifiers add the
+    jitted ``forward``, the ``forward_args`` of its last batch and that
+    batch's ``logits``."""
     arch = cb.canonical_arch(args.arch)
     if (args.prefill_chunk or args.prefix_cache) and args.ensemble > 1:
         raise SystemExit("--prefill-chunk/--prefix-cache are single-sample "
@@ -410,13 +429,14 @@ def main() -> None:
             raise SystemExit("--trace/--audit-collectives instrument the "
                              "step-level token serving loop; the classifier "
                              "path is fixed-batch (use --metrics-out)")
-        serve_classifier(arch, args)
-        return
+        return serve_classifier(arch, args)
     cfg = cb.get_config(arch, smoke=args.smoke)
     if cfg.frontend:
         raise SystemExit(f"{arch} uses a stubbed frontend; serve a token arch")
     mesh = make_serve_mesh(args)
-    params = T.init_lm(cfg, jax.random.key(args.seed))
+    # weights are held in the serving dtype, never as float32 masters
+    params = T.init_lm(cfg, jax.random.key(args.seed),
+                       dtype=cfg.activation_dtype)
     plan = None
     ensemble_set = None
     if args.ensemble > 1 and not (args.packed and args.binarize == "stoch"
@@ -445,8 +465,8 @@ def main() -> None:
         else:
             params = plan.pack(params, key=jax.random.key(args.seed + 1))
             dense_b, packed_b = packed_param_bytes(params)
-            print(f"packed weights: {dense_b/1e6:.1f}MB (bf16 dense) -> "
-                  f"{packed_b/1e6:.1f}MB "
+            print(f"packed weights ({plan.mode}): {dense_b/1e6:.1f}MB (bf16 "
+                  f"dense) -> {packed_b/1e6:.1f}MB "
                   f"({dense_b/max(packed_b,1):.1f}x smaller)")
 
     # mesh=None serves single-device; with a mesh the engine places the
@@ -565,6 +585,16 @@ def main() -> None:
                                               "(docs/ANALYSIS.md):"))
         if gate(findings):
             raise SystemExit(1)
+    return {"arch": arch, "plan": plan.mode if args.packed else "dense",
+            "requests": len(done), "tokens": n_tokens, "steps": steps,
+            "seconds": dt, "engine": engine, "completed": done,
+            "prefix_cache": (prefix_cache.stats()
+                             if prefix_cache is not None else None)}
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    serve(build_parser().parse_args(argv))
 
 
 if __name__ == "__main__":

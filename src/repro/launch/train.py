@@ -24,6 +24,7 @@ from repro.configs import base as cb
 from repro.core.policy import DEFAULT_POLICY, NONE_POLICY, BinarizePolicy
 from repro.data import synthetic as syn
 from repro.ft.failures import FailureInjector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import mnist_fc, transformer as T, vgg
 from repro.optim import schedules
 from repro.optim.sgd import adamw, sgd_momentum
@@ -133,6 +134,7 @@ def main() -> None:
     ap.add_argument("--history-out", default="")
     args = ap.parse_args()
 
+    enable_compile_cache()
     arch = cb.canonical_arch(args.arch)
     if arch in ("mnist_fc", "vgg16_cifar10"):
         state, step_fn, batch_fn = build_paper_model(arch, args)

@@ -27,14 +27,18 @@ class PackedLinear:
     packed: jax.Array               # (K // 32, N) int32
     scale: jax.Array | None         # (N,) f32 or None
     k: int                          # static original K
+    # static: which master dim is split over a mesh's "model" axis — "n"
+    # (out-channel), "k" (contraction words) or None — set by placement
+    # (distributed.sharding.place_packed_params)
+    tp: str | None = None
 
     def tree_flatten(self):
-        return (self.packed, self.scale), (self.k,)
+        return (self.packed, self.scale), (self.k, self.tp)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         packed, scale = children
-        return cls(packed, scale, aux[0])
+        return cls(packed, scale, *aux)
 
     @property
     def shape(self):
@@ -63,14 +67,18 @@ class XnorLinear:
     packed: jax.Array               # (K // 32, N) int32
     scale: jax.Array | None         # (N,) f32 or None
     k: int                          # static original K
+    # static: which master dim is split over a mesh's "model" axis — "n"
+    # (out-channel), "k" (contraction words) or None — set by placement
+    # (distributed.sharding.place_packed_params)
+    tp: str | None = None
 
     def tree_flatten(self):
-        return (self.packed, self.scale), (self.k,)
+        return (self.packed, self.scale), (self.k, self.tp)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         packed, scale = children
-        return cls(packed, scale, aux[0])
+        return cls(packed, scale, *aux)
 
     @property
     def shape(self):

@@ -35,7 +35,14 @@ def _stacked(init_one, key, n: int):
     return jax.vmap(init_one)(jax.random.split(key, n))
 
 
-def init_lm(cfg, key) -> dict:
+def init_lm(cfg, key, dtype=jnp.float32) -> dict:
+    """Parameter tree with every leaf drawn in float32 (the training
+    masters). A narrower ``dtype`` (serving: ``cfg.activation_dtype``)
+    holds the same draws cast to it; the draw and the cast run in one jit,
+    so the float32 tree is never resident."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return jax.jit(lambda k: jax.tree.map(
+            lambda a: a.astype(dtype), init_lm(cfg, k)))(key)
     keys = jax.random.split(key, 8)
     params: dict[str, Any] = {
         "embed": {"embedding": lm_init(keys[0], (cfg.vocab_size, cfg.d_model),

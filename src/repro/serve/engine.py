@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -46,6 +47,15 @@ from repro.models import transformer as T
 from repro.models.layers import (PackedConv, PackedLinear, XnorConv,
                                  XnorLinear)
 from repro.obs.trace import NULL_TRACER
+
+# Every serving program rounds to bf16 exactly where the model code casts.
+# With XLA's default excess precision a fusion may skip such a rounding
+# (e.g. square the f32 residual sum inside the RMSNorm fusion instead of
+# its bf16 value), and which ones it skips depends on the fusions — on a
+# mesh the collectives move the fusion boundaries, so the same step would
+# round differently on one device and on many, and greedy streams drift.
+_jit = functools.partial(
+    jax.jit, compiler_options={"xla_allow_excess_precision": False})
 
 
 def pack_params(params, policy, mode: str | BinarizeMode = "det",
@@ -235,7 +245,7 @@ class ServeEngine:
                              "mesh; pass mesh= as well (or drop plan=)")
         self.params = params
         self.sh = sh
-        self._prefill = jax.jit(
+        self._prefill = _jit(
             lambda p, toks, ml: T.prefill(cfg, p, toks, sh, max_len=ml),
             static_argnums=2)
         # The persistent cache is donated: the per-step KV write updates the
@@ -253,7 +263,7 @@ class ServeEngine:
             cache, lg = self._pin_state(cache, lg)
             return lg, cache
 
-        self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
+        self._decode = _jit(_decode_fn, donate_argnums=(1,))
 
         def _decode_chunk(p, cache, logits, d):
             """d fixed-shape greedy decode steps under one lax.scan: emits
@@ -271,8 +281,8 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return cache, logits, jnp.moveaxis(toks, 0, 1)  # (n_slots, d)
 
-        self._decode_chunk = jax.jit(_decode_chunk, static_argnums=3,
-                                     donate_argnums=(1, 2))
+        self._decode_chunk = _jit(_decode_chunk, static_argnums=3,
+                                  donate_argnums=(1, 2))
 
         def _prefill_into(p, cache, logits, prompt, slot, ml):
             lg, one = T.prefill(cfg, p, prompt, sh, max_len=ml)
@@ -282,7 +292,7 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return logits, cache
 
-        self._prefill_into = jax.jit(_prefill_into, static_argnums=5)
+        self._prefill_into = _jit(_prefill_into, static_argnums=5)
 
         def _prefill_chunk(p, cache, logits, chunk_toks, slot, offset):
             """One prefill chunk for one slot, no decode (the ramp-up /
@@ -294,7 +304,7 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return logits, cache
 
-        self._prefill_chunk = jax.jit(_prefill_chunk)
+        self._prefill_chunk = _jit(_prefill_chunk)
 
         def _decode_prefill(p, cache, logits, tok, keep, chunk_toks, slot,
                             offset):
@@ -317,8 +327,7 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return logits, cache
 
-        self._decode_prefill = jax.jit(_decode_prefill,
-                                       donate_argnums=(1, 2))
+        self._decode_prefill = _jit(_decode_prefill, donate_argnums=(1, 2))
 
         def _splice(cache, logits, one, lg, slot, use_lg):
             """Splice a prefix-cache snapshot (batch-1 rows) into a slot;
@@ -331,7 +340,7 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return logits, cache
 
-        self._splice = jax.jit(_splice, static_argnums=5)
+        self._splice = _jit(_splice, static_argnums=5)
 
         def _extract(cache, logits, slot):
             """Batch-1 snapshot of one slot's cache rows + logits row (the
@@ -340,7 +349,7 @@ class ServeEngine:
             lg = jax.lax.dynamic_slice_in_dim(logits, slot, 1, axis=0)
             return one, lg
 
-        self._extract = jax.jit(_extract)
+        self._extract = _jit(_extract)
 
         # K = 1 (or no stochastic rows) degrades to the plain single-sample
         # path above on ensemble.base — structurally the same program, so
@@ -408,7 +417,7 @@ class ServeEngine:
             rep_lg, rep_cache = jax.vmap(one, in_axes=0, axis_size=k)(stacked)
             return ensemble_stats(rep_lg), rep_cache
 
-        self._prefill_ens = jax.jit(_ens_prefill, static_argnums=3)
+        self._prefill_ens = _jit(_ens_prefill, static_argnums=3)
 
         def _ens_decode(stacked, base, cache, tok):
             def one(st, c):
@@ -420,7 +429,7 @@ class ServeEngine:
 
         # same donation contract as the single-sample _decode: the
         # K-replica cache updates in place, callers rebind their state
-        self._decode_ens = jax.jit(_ens_decode, donate_argnums=(2,))
+        self._decode_ens = _jit(_ens_decode, donate_argnums=(2,))
 
         def _ens_prefill_into(stacked, base, cache, logits, agree, var,
                               prompt, slot, ml):
@@ -438,7 +447,7 @@ class ServeEngine:
                     upd(var, es.variance, slot, 0),
                     self._pin_ens_cache(cache))
 
-        self._ens_prefill_into = jax.jit(_ens_prefill_into, static_argnums=8)
+        self._ens_prefill_into = _jit(_ens_prefill_into, static_argnums=8)
 
     def jit_entries(self) -> dict:
         """Name -> jitted entry point, for observability wrappers (the

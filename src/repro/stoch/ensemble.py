@@ -137,17 +137,22 @@ def place_replicas(mesh, rs: ReplicaSet,
     the plan's ``replica_axis`` prepended (:func:`replica_specs`). A
     ``replica_axis`` of None (or a K not divisible by the axis size)
     replicates the stack."""
-    from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.distributed.sharding import place_packed_params
+    from repro.distributed.sharding import (place_packed_params,
+                                            sanitize_spec, with_model_split)
 
     plan = plan if plan is not None else rs.plan
     base = place_packed_params(mesh, rs.base, plan)
     specs = replica_specs(rs, mesh=mesh)
-    stacked = {
-        path: jax.tree.map(
+    stacked = {}
+    for path, node in rs.stacked.items():
+        placed = jax.tree.map(
             lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
             node, specs[path])
-        for path, node in rs.stacked.items()}
+        # the per-replica node keeps its row's split (replica axis aside)
+        row = getattr(node, "master_shape", ())[1:]
+        col = sanitize_spec(mesh, rs.plan[path].pspec or P(), row)
+        stacked[path] = with_model_split(placed, col)
     return ReplicaSet(base=base, stacked=stacked, k=rs.k, paths=rs.paths,
                       plan=rs.plan)

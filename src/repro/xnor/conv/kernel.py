@@ -1,56 +1,26 @@
-"""Pallas TPU kernel for fused patch extraction + sign-binarize + bitpack.
+"""Fused sign-binarize + bitpack of im2col patches, on the sign-pack kernel.
 
 ``patch_pack_pallas`` turns a zero-padded (B, Hp, Wp, C) activation into the
-bitpacked im2col matrix (B, OH, OW, kh*kw*ceil(C/32)) int32 in one pass, so
-the full-width conv activation never round-trips through HBM between
-binarization and the popcount GEMM — only the 1-bit packed patches leave the
-chip (the conv analogue of ``xnor.kernel.sign_pack_pallas``).
+bitpacked im2col matrix (B, OH, OW, kh*kw*ceil(C/32)) int32. Only 1-bit
+packed words leave the Pallas kernel: the full-width conv activation is read
+once and never written back unpacked (the conv analogue of
+``xnor.kernel.sign_pack_pallas``).
 
-The per-tap word layout (see ``xnor.conv.packing``) is what makes the fusion
+The per-tap word layout (see ``xnor.conv.packing``) is what makes this
 cheap: channels pack per *pixel* once — word j of pixel (y, x) is the same in
-every patch that covers that pixel — so the kernel packs the whole image to
-(Hp, Wp, cw) words and then only *gathers* words per tap. Tap gathers use
-static strided-window reshapes (slice [dy : dy+OH*s] -> (OH, s, ...) ->
-[:, 0]), which lower to plain slices; the wrapper pads the image with s-1
-slack rows/cols of zeros so every window is in range.
-
-Grid is (B,): one program per image, the whole padded image resident in
-VMEM. That is the right trade at the paper's CIFAR scale (the largest VGG
-slab, 34x34x512 f32, is ~2.3 MB); bigger images would need an OH-blocked
-grid, which the blocked popcount GEMM downstream already supports.
+every patch that covers that pixel — so the kernel packs every pixel's
+channels to ``cw`` words (``sign_pack_rows`` over the (B*Hp*Wp, C) rows),
+and the taps are then gathered from the packed (B, Hp, Wp, cw) words by
+strided slices. The gather moves packed words only, 32x fewer bytes than
+the f32 activation.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.compat import CompilerParams as _CompilerParams
-from repro.core.packing import PACK
-
-
-def _patch_pack_kernel(x_ref, o_ref, *, ksize, stride, oh: int, ow: int,
-                       c: int):
-    """(1, Hp, Wp, C) float -> (1, OH, OW, kh*kw*cw) int32 packed patches."""
-    kh, kw = ksize
-    sh, sw = stride
-    cw = (c + PACK - 1) // PACK
-    img = x_ref[0]                                   # (Hp, Wp, C)
-    bits = (img > 0).astype(jnp.uint32)              # Eq. (1): x <= 0 -> bit 0
-    if cw * PACK != c:                               # channel pad: bit 0
-        bits = jnp.pad(bits, ((0, 0), (0, 0), (0, cw * PACK - c)))
-    hp, wp = bits.shape[0], bits.shape[1]
-    shifts = jnp.arange(PACK, dtype=jnp.uint32)
-    words = jnp.sum(bits.reshape(hp, wp, cw, PACK) << shifts, axis=-1,
-                    dtype=jnp.uint32)                # (Hp, Wp, cw): per pixel
-    taps = []
-    for dy in range(kh):
-        for dx in range(kw):
-            t = words[dy:dy + oh * sh, dx:dx + ow * sw]
-            taps.append(t.reshape(oh, sh, ow, sw, cw)[:, 0, :, 0, :])
-    o_ref[0] = jnp.concatenate(taps, axis=-1).astype(jnp.int32)
+from repro.xnor.kernel import sign_pack_rows
+from repro.xnor.packing import pad_features
 
 
 def patch_pack_pallas(
@@ -63,23 +33,18 @@ def patch_pack_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Fused im2col + sign + bitpack over an already spatially zero-padded
-    (B, Hp, Wp, C) input (``ops.py`` computes the padding, including the
-    stride slack). Returns (B, OH, OW, kh*kw*ceil(C/32)) int32."""
+    (B, Hp, Wp, C) input (``ops.py`` computes the padding). Returns
+    (B, OH, OW, kh*kw*ceil(C/32)) int32."""
     b, hp, wp, c = xp.shape
     kh, kw = ksize
     sh, sw = stride
-    if hp < kh - 1 + oh * sh or wp < kw - 1 + ow * sw:
+    need = ((oh - 1) * sh + kh, (ow - 1) * sw + kw)
+    if hp < need[0] or wp < need[1]:
         raise ValueError(
             f"padded image {(hp, wp)} too small for k={ksize} s={stride} "
-            f"out={(oh, ow)} (needs {(kh - 1 + oh * sh, kw - 1 + ow * sw)})")
-    k32 = kh * kw * ((c + PACK - 1) // PACK)
-    return pl.pallas_call(
-        functools.partial(_patch_pack_kernel, ksize=ksize, stride=stride,
-                          oh=oh, ow=ow, c=c),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, hp, wp, c), lambda i: (i, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, oh, ow, k32), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, oh, ow, k32), jnp.int32),
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(xp)
+            f"out={(oh, ow)} (needs {need})")
+    rows = pad_features(xp.reshape(b * hp * wp, c))  # channel pad: bit 0
+    words = sign_pack_rows(rows, interpret=interpret).reshape(b, hp, wp, -1)
+    taps = [words[:, dy:dy + (oh - 1) * sh + 1:sh, dx:dx + (ow - 1) * sw + 1:sw]
+            for dy in range(kh) for dx in range(kw)]
+    return jnp.concatenate(taps, axis=-1)

@@ -39,19 +39,12 @@ def sign_and_pack_patches(
     The full-width activation never leaves the kernel unpacked; only the
     packed patch words are written back. Spatial zero padding and per-tap
     channel padding both carry sign bit 0 (see ``xnor.conv.packing``)."""
-    b, h, w, c = x.shape
-    kh, kw = ksize
-    sh, sw = stride
+    _, h, w, _ = x.shape
     oh, ow, ((ph0, ph1), (pw0, pw1)) = conv_geometry(h, w, ksize, stride,
                                                      padding)
     if not use_pallas:
         return ref.sign_pack_patches_ref(x, ksize, stride, padding)
-    # Stride slack: the kernel's windowed reshape reads [dy, dy + OH*sh) —
-    # up to sh-1 rows past the last tap — so over-pad with zeros (bit 0,
-    # never selected into a patch).
-    eh = max(0, kh - 1 + oh * sh - (h + ph0 + ph1))
-    ew = max(0, kw - 1 + ow * sw - (w + pw0 + pw1))
-    xp = jnp.pad(x, ((0, 0), (ph0, ph1 + eh), (pw0, pw1 + ew), (0, 0)))
+    xp = jnp.pad(x, ((0, 0), (ph0, ph1), (pw0, pw1), (0, 0)))
     return patch_pack_pallas(xp, ksize=ksize, stride=stride, oh=oh, ow=ow,
                              interpret=not _on_tpu())
 

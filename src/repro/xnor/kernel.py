@@ -22,9 +22,10 @@ Layouts: a_packed (M, K//32) int32   (xnor.packing — packed along last axis)
 
 ``k_total`` is the *true* contraction length: 0-bit padding on both operands
 XORs to 0, contributes nothing to the popcount, and drops out of the formula
-(see xnor.packing). Block constraints: block_m multiple of 8, block_k a
-multiple of 32 with block_k//32 words per a-block sublane row; on real TPUs
-prefer block_k >= 512 so the packed lane dimension stays reasonably wide.
+(see xnor.packing). Block constraints: block_m a multiple of 8; a packed
+word dim that is the last (lane) dim of a block — a's K//32 and sign_pack's
+output — must be whole or a multiple of 128 words on a TPU
+(:func:`lane_words` picks such a block).
 """
 from __future__ import annotations
 
@@ -35,15 +36,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import CompilerParams as _CompilerParams
+from repro.core.compat import ceil_to
 from repro.core.packing import PACK
+
+LANES = 128
+
+
+def lane_words(k32: int, words: int) -> int:
+    """Packed words per block along a lane (last) dim of ``k32`` words that
+    the TPU block rule accepts: the whole dim when it fits in 128 lanes,
+    else ``words`` rounded up to a multiple of 128 (the caller pads the dim
+    to a multiple of the result with 0-bit words)."""
+    return k32 if k32 <= LANES else ceil_to(words, LANES)
 
 
 def _block_popcount_dot(a_words: jax.Array, w_words: jax.Array) -> jax.Array:
-    """(bm, bk32) x (bk32, bn) packed words -> (bm, bn) int32 XOR-popcount sum."""
-    x = jnp.bitwise_xor(a_words.astype(jnp.uint32)[:, :, None],
-                        w_words.astype(jnp.uint32)[None, :, :])
-    return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=1)
+    """(bm, bk32) x (bk32, bn) packed words -> (bm, bn) int32 XOR-popcount sum.
+
+    One word at a time, an (bm, 1) column of ``a`` against a (1, bn) row of
+    ``w``, so every intermediate is one (bm, bn) tile. Statically unrolled:
+    the TPU lowering slices lanes only at offsets it knows."""
+    acc = jnp.zeros((a_words.shape[0], w_words.shape[1]), jnp.int32)
+    for j in range(a_words.shape[1]):
+        acc += jax.lax.population_count(a_words[:, j:j + 1]
+                                        ^ w_words[j:j + 1, :])
+    return acc
 
 
 def _xnor_kernel(a_ref, w_ref, o_ref, acc_ref, *, nk: int, k_total: int):
@@ -130,42 +147,73 @@ def xnor_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(*args)
 
 
-def _sign_pack_kernel(x_ref, o_ref, *, bk: int):
-    """(bm, bk) float -> (bm, bk//32) int32: Eq. (1) sign bit, packed lanes."""
-    bm = x_ref.shape[0]
-    ones = (x_ref[...] > 0).astype(jnp.uint32)
-    bits = ones.reshape(bm, bk // PACK, PACK)
-    shifts = jnp.arange(PACK, dtype=jnp.uint32)[None, None, :]
-    o_ref[...] = jnp.sum(bits << shifts, axis=2, dtype=jnp.uint32).astype(
-        jnp.int32)
+def _sign_pack_kernel(x_ref, o_ref):
+    """(bm, bk) float -> (bm, bk//32) int32: Eq. (1) sign bit, packed lanes.
+
+    Splitting the lane dim into (words, 32) has no TPU lowering, so the
+    pack is a matmul on the MXU: ``bits @ P`` with ``P[32j + b, j] = 2**b``.
+    The low and high 16 bits go through separate bf16 matmuls, so every
+    product and every f32 partial sum is an exact integer below 2**16."""
+    # compared in f32: the v5e vector unit has no bf16 compare
+    bits = (x_ref[...].astype(jnp.float32) > 0).astype(jnp.bfloat16)
+    bk = bits.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bk, bk // PACK), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bk, bk // PACK), 1)
+    bit = row % PACK
+    mine = row // PACK == col
+    weight = (1 << (bit % 16)).astype(jnp.float32)
+
+    def half(sel):
+        p = jnp.where(mine & sel, weight, 0.0).astype(jnp.bfloat16)
+        return jnp.dot(bits, p,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    o_ref[...] = (half(bit >= 16) << 16) | half(bit < 16)
 
 
 def sign_pack_pallas(
     x: jax.Array,
     *,
     block_m: int = 128,
-    block_k: int = 512,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused sign-binarize + bitpack: (M, K) -> (M, K//32) int32.
-    M % block_m == 0, K % block_k == 0, block_k % 32 == 0 (ops.py pads)."""
+    M % block_m == 0, K % block_k == 0, block_k % 32 == 0; ``block_k``
+    defaults to the whole K (:func:`sign_pack_rows` pads any shape)."""
     m, kdim = x.shape
+    block_k = kdim if block_k is None else block_k
     if m % block_m or kdim % block_k or block_k % PACK:
         raise ValueError(f"bad blocks ({block_m},{block_k}) for shape {(m, kdim)}")
     grid = (m // block_m, kdim // block_k)
     x_spec = pl.BlockSpec((block_m, block_k), lambda i, j: (i, j))
     o_spec = pl.BlockSpec((block_m, block_k // PACK), lambda i, j: (i, j))
     return pl.pallas_call(
-        functools.partial(_sign_pack_kernel, bk=block_k),
+        _sign_pack_kernel,
         grid=grid,
         in_specs=[x_spec],
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((m, kdim // PACK), jnp.int32),
         interpret=interpret,
     )(x)
+
+
+def sign_pack_rows(x2: jax.Array, *, block_m: int = 128, block_k: int = 512,
+                   interpret: bool = False) -> jax.Array:
+    """:func:`sign_pack_pallas` on any (M, K) with K % 32 == 0: pads M and K
+    to TPU-legal blocks (0 packs to bit 0, then is sliced off)."""
+    m, kdim = x2.shape
+    k32 = kdim // PACK
+    bk32 = lane_words(k32, block_k // PACK)
+    bm = min(block_m, ceil_to(m, 8))
+    mp, kp = ceil_to(m, bm), ceil_to(k32, bk32) * PACK
+    xp = jnp.pad(x2, ((0, mp - m), (0, kp - kdim)))
+    packed = sign_pack_pallas(xp, block_m=bm, block_k=bk32 * PACK,
+                              interpret=interpret)
+    return packed[:m, :k32]

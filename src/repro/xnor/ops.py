@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.core.compat import ceil_to as _ceil_to, on_tpu as _on_tpu
 from repro.core.packing import PACK
 from repro.xnor import ref
-from repro.xnor.kernel import sign_pack_pallas, xnor_matmul_pallas
+from repro.xnor.kernel import lane_words, sign_pack_rows, xnor_matmul_pallas
 from repro.xnor.packing import pad_features
 
 
@@ -38,12 +38,9 @@ def sign_and_pack(
     m = x2.shape[0]
     if not use_pallas or m * kdim < block_m * block_k:
         return ref.sign_pack_ref(x2).reshape(*lead, k32)
-    bm = min(block_m, _ceil_to(m, 8))
-    mp, kp = _ceil_to(m, bm), _ceil_to(x2.shape[1], block_k)
-    xp = jnp.pad(x2, ((0, mp - m), (0, kp - x2.shape[1])))
-    packed = sign_pack_pallas(xp, block_m=bm, block_k=block_k,
-                              interpret=not _on_tpu())
-    return packed[:m, :k32].reshape(*lead, k32)
+    packed = sign_pack_rows(x2, block_m=block_m, block_k=block_k,
+                            interpret=not _on_tpu())
+    return packed.reshape(*lead, k32)
 
 
 def xnor_matmul_packed(
@@ -103,14 +100,14 @@ def _xnor_matmul_packed(
         return out.reshape(*lead, n)
 
     bm = min(block_m, _ceil_to(m, 8))
-    bk32 = block_k // PACK
+    bk32 = lane_words(k32, block_k // PACK)
     mp, np_, kp32 = _ceil_to(m, bm), _ceil_to(n, block_n), _ceil_to(k32, bk32)
     ap = jnp.pad(a2, ((0, mp - m), (0, kp32 - k32)))
     wp = jnp.pad(w_packed, ((0, kp32 - k32), (0, np_ - n)))
     sp = None if scale is None else jnp.pad(scale, (0, np_ - n))
     out = xnor_matmul_pallas(
         ap, wp, sp, k_total=k,
-        block_m=bm, block_n=block_n, block_k=block_k,
+        block_m=bm, block_n=block_n, block_k=bk32 * PACK,
         out_dtype=out_dtype, interpret=not _on_tpu(),
     )
     return out[:m, :n].reshape(*lead, n)

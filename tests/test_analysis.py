@@ -25,6 +25,8 @@ from repro.engine import ExecutionPlan
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), os.pardir,
                           "benchmarks", "golden_plans")
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def golden_plan_files():
     out = []
@@ -421,7 +423,7 @@ class TestLiveAnalysis:
                 from repro.analysis.findings import findings_to_json
                 print("FINDINGS " +
                       json.dumps(findings_to_json(_live_child("det"))))
-            """)], cwd="/root/repo", capture_output=True, text=True,
+            """)], cwd=REPO, capture_output=True, text=True,
             timeout=560)
         assert out.returncode == 0, (out.stdout[-500:], out.stderr[-2000:])
         line = [ln for ln in out.stdout.splitlines()

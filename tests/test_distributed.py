@@ -7,6 +7,7 @@ divisibility invariants, and distributed equivalence of the sharded train
 step vs single-device execution.
 """
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -16,10 +17,12 @@ import pytest
 from repro.configs import base as cb
 from repro.distributed.sharding import divisibility_report
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _run(code: str, timeout=560):
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         cwd="/root/repo", capture_output=True, text=True,
+                         cwd=REPO, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, (out.stdout[-500:], out.stderr[-2000:])
     return out.stdout
@@ -67,37 +70,16 @@ class TestShardingHelpers:
         assert sh.act(x, "btd") is x          # identity, no device state
         assert ShardCtx(mesh=None, enable=False).act(x, "btf") is x
 
-    def test_mesh_context_spans_both_jax_apis(self, monkeypatch):
-        """jax >= 0.5 exposes jax.set_mesh; 0.4.x enters the Mesh object.
-        The shim must return a context manager on both branches."""
-        import jax
-        from repro.distributed.sharding import mesh_context
+    def test_make_mesh_axes_are_auto(self):
+        """Every mesh comes from make_mesh, with Auto axes: under jax's
+        default Explicit axes the embedding gather of the mesh-sharded
+        serving path refuses to lower."""
+        from jax.sharding import AxisType
+        from repro.distributed.sharding import make_mesh
 
-        class FakeMesh:
-            entered = exited = False
-
-            def __enter__(self):
-                FakeMesh.entered = True
-                return self
-
-            def __exit__(self, *a):
-                FakeMesh.exited = True
-                return False
-
-        # branch 1: jax.set_mesh present — the shim must call it
-        calls = []
-        monkeypatch.setattr(jax, "set_mesh",
-                            lambda m: calls.append(m) or FakeMesh(),
-                            raising=False)
-        with mesh_context("the-mesh"):
-            pass
-        assert calls == ["the-mesh"]
-        # branch 2: no jax.set_mesh — the mesh object itself is the context
-        monkeypatch.delattr(jax, "set_mesh", raising=False)
-        m = FakeMesh()
-        with mesh_context(m) as entered:
-            assert entered is m
-        assert FakeMesh.entered and FakeMesh.exited
+        mesh = make_mesh((1, 1), ("data", "model"))
+        assert mesh.axis_names == ("data", "model")
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
     def test_batch_axes_with_and_without_pod(self):
         from types import SimpleNamespace
@@ -195,7 +177,8 @@ class TestSmallMeshDryRun:
             import jax, jax.numpy as jnp
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
-            from repro.distributed.sharding import ShardCtx, mesh_context, params_pspecs
+            from repro.distributed.sharding import (ShardCtx, make_mesh,
+                                                    mesh_context, params_pspecs)
             from repro.launch import specs as SP
             from repro.models import transformer as T
             from repro.optim import schedules
@@ -203,7 +186,7 @@ class TestSmallMeshDryRun:
             from repro.train import steps as ST
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             cfg = cb.get_config("starcoder2_3b", smoke=True)
             sh = ShardCtx(mesh)
             opt = sgd_momentum(schedules.constant(1e-2))
@@ -240,7 +223,7 @@ class TestSmallMeshDryRun:
             import jax, jax.numpy as jnp, numpy as np
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
-            from repro.distributed.sharding import ShardCtx, mesh_context
+            from repro.distributed.sharding import ShardCtx, make_mesh, mesh_context
             from repro.launch import specs as SP
             from repro.models import transformer as T
             from repro.optim import schedules
@@ -259,7 +242,7 @@ class TestSmallMeshDryRun:
             s0 = ST.init_train_state(jax.tree.map(jnp.copy, params), opt)
             s0, m0 = jax.jit(step0)(s0, batch)
             # sharded
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             sh = ShardCtx(mesh)
             step1 = ST.make_train_step(ST.make_lm_loss(cfg, sh), opt, "det",
                                        DEFAULT_POLICY)
@@ -294,10 +277,11 @@ class TestSmallMeshDryRun:
                         "--out", "/tmp/dr_smoke_test", "--force"]
             # monkeypatch the production mesh to the 8-device debug mesh
             import jax
+            from repro.distributed.sharding import make_mesh
             from repro.launch import mesh as M
             M.make_production_mesh = lambda multi_pod=False: (
-                jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-                if multi_pod else jax.make_mesh((2, 4), ("data", "model")))
+                make_mesh((2, 2, 2), ("pod", "data", "model"))
+                if multi_pod else make_mesh((2, 4), ("data", "model")))
             from repro.launch import dryrun
             dryrun.make_production_mesh = M.make_production_mesh
             dryrun.main()
@@ -321,6 +305,7 @@ class TestMeshShardedServing:
             import sys; sys.path.insert(0, "src")
             import json
             import jax, numpy as np
+            from repro.distributed.sharding import make_mesh
             from jax.sharding import PartitionSpec as P
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
@@ -329,7 +314,7 @@ class TestMeshShardedServing:
             from repro.serve.batcher import SlotBatcher
             from repro.serve.engine import ServeEngine, stream_serve
 
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             cfg = cb.get_config("starcoder2_3b", smoke=True)
             params = T.init_lm(cfg, jax.random.key(0))
 
@@ -357,7 +342,7 @@ class TestMeshShardedServing:
             kspec = state.cache["k"].sharding.spec
             # pure-TP mesh (no data axis): placement must not crash and
             # slot dims replicate
-            tp_mesh = jax.make_mesh((4,), ("model",))
+            tp_mesh = make_mesh((4,), ("model",))
             tp_state = ServeEngine(cfg, packed, mesh=tp_mesh).init_decode(
                 2, 8, 4)
             tp_pos = list(tp_state.cache["pos"].sharding.spec)
@@ -394,6 +379,7 @@ class TestMeshShardedServing:
             import sys; sys.path.insert(0, "src")
             import json
             import jax, numpy as np
+            from repro.distributed.sharding import make_mesh
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
             from repro.engine import compile_plan
@@ -401,7 +387,7 @@ class TestMeshShardedServing:
             from repro.serve.batcher import SlotBatcher
             from repro.serve.engine import ServeEngine, stream_serve
 
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             cfg = cb.get_config("starcoder2_3b", smoke=True)
             params = T.init_lm(cfg, jax.random.key(0))
 
@@ -443,6 +429,7 @@ class TestMeshShardedServing:
             import sys; sys.path.insert(0, "src")
             import json
             import jax, numpy as np
+            from repro.distributed.sharding import make_mesh
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
             from repro.engine import compile_plan
@@ -466,7 +453,7 @@ class TestMeshShardedServing:
             res = {}
             for rax, shape, names in [("data", (4,), ("data",)),
                                       ("model", (2, 2), ("data", "model"))]:
-                mesh = jax.make_mesh(shape, names)
+                mesh = make_mesh(shape, names)
                 plan = compile_plan(params, DEFAULT_POLICY, "stoch",
                                     warn=False, mesh=mesh, replica_axis=rax)
                 rs = sample_replicas(params, plan, jax.random.key(1), 4)
@@ -526,11 +513,12 @@ class TestPipelineParallel:
             import sys; sys.path.insert(0, "src")
             import json
             import jax, jax.numpy as jnp, numpy as np
+            from repro.distributed.sharding import make_mesh
             from repro.distributed.pipeline_parallel import (
                 pipeline_forward, reference_forward, run_pipeline)
 
             n_stages, n_micro, mb, d = 4, 8, 2, 16
-            mesh = jax.make_mesh((n_stages,), ("stage",))
+            mesh = make_mesh((n_stages,), ("stage",))
             def stage_fn(p, x):
                 return jnp.tanh(x @ p["w"] + p["b"])
             params = {

@@ -1,5 +1,6 @@
 """HLO analyzer correctness: FLOPs vs analytic, trip-count attribution,
 collective accounting, shape parsing."""
+import os
 import textwrap
 
 import jax
@@ -8,6 +9,8 @@ import pytest
 
 from repro.core import hlo_analysis as H
 from repro.core import roofline as R
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestShapeParsing:
@@ -85,8 +88,8 @@ class TestCollectives:
             import jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import hlo_analysis as H
-            from repro.distributed.sharding import mesh_context
-            mesh = jax.make_mesh((4,), ("model",))
+            from repro.distributed.sharding import make_mesh, mesh_context
+            mesh = make_mesh((4,), ("model",))
             def f(a, b):
                 return (a @ b).sum()
             with mesh_context(mesh):
@@ -100,7 +103,7 @@ class TestCollectives:
             print(json.dumps({"ar": cost.collective_bytes_by_kind.get(
                 "all-reduce", 0), "total": cost.collective_bytes}))
         """)
-        out = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
+        out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
                              capture_output=True, text=True, timeout=300)
         assert out.returncode == 0, out.stderr[-800:]
         res = json.loads(out.stdout.strip().splitlines()[-1])

@@ -20,10 +20,12 @@ from repro.obs.collectives import (ACT_BYTES, CollectiveAudit, audit_hlo,
 GOLDEN = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
                       "golden_plans", "collectives.json")
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _run(code: str, timeout=560):
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         cwd="/root/repo", capture_output=True, text=True,
+                         cwd=REPO, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, (out.stdout[-500:], out.stderr[-2000:])
     return out.stdout
@@ -85,6 +87,7 @@ class TestPredictRowCollective:
         """A mesh-compiled plan's report predicts a collective for every
         TP-sharded row and formats it into the table."""
         import jax
+        from repro.distributed.sharding import make_mesh
 
         from repro.configs import base as cb
         from repro.core.policy import DEFAULT_POLICY
@@ -92,7 +95,7 @@ class TestPredictRowCollective:
         from repro.engine.plan import format_plan_table, plan_report
         from repro.models import transformer as T
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         cfg = cb.get_config("starcoder2_3b", smoke=True)
         params = jax.eval_shape(lambda: T.init_lm(cfg, jax.random.key(0)))
         plan = compile_plan(params, DEFAULT_POLICY, "det", warn=False,
@@ -126,11 +129,12 @@ class TestAuditVsHloAnalysis:
             import sys, json
             sys.path.insert(0, "src")
             import jax, jax.numpy as jnp
+            from repro.distributed.sharding import make_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core import hlo_analysis as H
             from repro.obs.collectives import audit_hlo
 
-            mesh = jax.make_mesh((4,), ("model",))
+            mesh = make_mesh((4,), ("model",))
             x = jax.device_put(jnp.ones((8, 64), jnp.float32),
                                NamedSharding(mesh, P(None, "model")))
             w = jax.device_put(jnp.ones((64, 16), jnp.float32),
@@ -218,6 +222,6 @@ class TestGoldenShardedAudit:
 
     def test_prefill_exact_counts(self, measured):
         pre = CollectiveAudit.from_json(measured["det"]["prefill_into"])
-        assert pre.counts == {"all-gather": 1, "all-reduce": 12,
-                              "all-to-all": 12, "collective-permute": 8}
-        assert pre.total_count == 33
+        assert pre.counts == {"all-gather": 1, "all-reduce": 15,
+                              "all-to-all": 8, "collective-permute": 8}
+        assert pre.total_count == 32

@@ -11,6 +11,7 @@ oracle exactly. The forced-mesh rows run in subprocesses (marked ``slow``;
 CI runs them as their own step).
 """
 import dataclasses
+import os
 import subprocess
 import sys
 import textwrap
@@ -30,6 +31,8 @@ ARCH = "starcoder2_3b"
 PROMPT_LEN = 8
 MAX_NEWS = [3, 5, 2, 4, 3]
 CAP = 5
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -185,7 +188,7 @@ class TestEnsembleConformance:
 
 def _run(code: str, timeout=560):
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         cwd="/root/repo", capture_output=True, text=True,
+                         cwd=REPO, capture_output=True, text=True,
                          timeout=timeout)
     assert out.returncode == 0, (out.stdout[-500:], out.stderr[-2000:])
     return out.stdout
@@ -206,6 +209,7 @@ class TestForcedMeshMatrix:
             sys.path.insert(0, "src")
             import numpy as np
             import jax, jax.numpy as jnp
+            from repro.distributed.sharding import make_mesh
             from repro.configs import base as cb
             from repro.core.policy import DEFAULT_POLICY
             from repro.engine import compile_plan
@@ -218,7 +222,7 @@ class TestForcedMeshMatrix:
             plan = compile_plan(params, DEFAULT_POLICY, "{mode}", warn=False)
             packed = plan.pack(params)
             oracle_eng = ServeEngine(cfg, packed)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             eng = ServeEngine(cfg, packed, mesh=mesh, plan=plan)
 
             rng = np.random.default_rng(0)
@@ -257,6 +261,7 @@ class TestForcedMeshMatrix:
             sys.path.insert(0, "src")
             import numpy as np
             import jax, jax.numpy as jnp
+            from repro.distributed.sharding import make_mesh
             from repro.configs import base as cb
             from repro.models import transformer as T
             from repro.serve import ServeEngine, SlotBatcher, stream_serve
@@ -264,7 +269,7 @@ class TestForcedMeshMatrix:
             cfg = cb.get_config("starcoder2_3b", smoke=True)
             params = T.init_lm(cfg, jax.random.key(0))
             oracle_eng = ServeEngine(cfg, params)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_mesh((2, 2), ("data", "model"))
             eng = ServeEngine(cfg, params, mesh=mesh)
 
             rng = np.random.default_rng(0)
