@@ -176,9 +176,7 @@ def _sharded_child(modes: list[str], n: int, cap: int, slots: int,
     ``widen`` scales d_model / n_heads / d_ff by an integer factor (the
     model-size sweep: where per-device compute grows, the fixed per-step
     collective cost amortizes). Both engines stay *untraced* (the
-    ``NULL_TRACER`` default — no ``tracer.fence``): a fencing tracer
-    ``block_until_ready``'s every dispatch, serializing the async pipeline
-    and understating exactly the sharded rows this compares. The returned
+    ``NULL_TRACER`` default). The returned
     ``manifest`` is this subprocess's own ``run_manifest`` — it, not the
     parent, sees the forced device count and mesh shape."""
     import dataclasses

@@ -376,13 +376,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace", default="", metavar="OUT.json",
                     help="record a Chrome trace of the serving loop "
-                         "(span per step: refill/prefill/sample/record/"
-                         "decode, dispatch vs device time) and write it "
+                         "(span per step: refill/prefill/sample/"
+                         "token_sync/record/decode enqueue) and write it "
                          "here — open in Perfetto; token archs only")
-    ap.add_argument("--no-trace-fence", action="store_true",
-                    help="with --trace: skip block_until_ready fencing "
-                         "(dispatch-only spans; does not serialize the "
-                         "async pipeline)")
     ap.add_argument("--metrics-out", default="", metavar="OUT.json",
                     help="write serving metrics (tok/s, TTFT, per-step "
                          "latency p50/p95/p99, queue depth, slot "
@@ -477,7 +473,7 @@ def serve(args) -> dict:
     if args.trace:
         from repro.obs import Tracer
 
-        tracer = Tracer(fence=not args.no_trace_fence)
+        tracer = Tracer()
     metrics = None
     if args.metrics_out:
         from repro.obs import MetricsRegistry

@@ -179,48 +179,52 @@ def decode_attention(cfg, params, x, k_cache, v_cache, pos, sh=None):
     Returns (out, k_cache, v_cache)."""
     b, _, _ = x.shape
     s_cache = k_cache.shape[1]
-    # "qkv": under a decode ShardCtx this is the block's ONE gather — the
-    # col-parallel qkv matmul's output replicates here, so the split /
-    # RoPE / cache write / softmax / PV einsum below are all device-local
-    qkv = apply_linear(params["w_qkv"], x, params.get("b_qkv"),
-                       sh=sh, kind="qkv")
-    q, k, v = _split_qkv(cfg, qkv)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    with jax.named_scope("attention"):
+        # "qkv": under a decode ShardCtx this is the block's ONE gather — the
+        # col-parallel qkv matmul's output replicates here, so the split /
+        # RoPE / cache write / softmax / PV einsum below are all device-local
+        qkv = apply_linear(params["w_qkv"], x, params.get("b_qkv"),
+                           sh=sh, kind="qkv")
+        q, k, v = _split_qkv(cfg, qkv)
+        q = apply_rope(q, pos[:, None], cfg.rope_theta)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta)
 
     write_idx = pos % s_cache if cfg.sliding_window else jnp.minimum(pos, s_cache - 1)
-    # One-hot select instead of a batched scatter: GSPMD cannot partition a
-    # scatter whose index vector spans a sharded batch dim (it replicated
-    # the updates with a collective-permute + all-gather pair per cache,
-    # per layer, per step), while this jnp.where is elementwise — fully
-    # local under the slot-sharded serving cache layout. Selection is
-    # bit-exact (no arithmetic on cache values).
-    write_hot = (jnp.arange(s_cache)[None, :] == write_idx[:, None]
-                 )[:, :, None, None]                       # (B, S, 1, 1)
-    k_cache = jnp.where(write_hot, k[:, :1].astype(k_cache.dtype), k_cache)
-    v_cache = jnp.where(write_hot, v[:, :1].astype(v_cache.dtype), v_cache)
+    with jax.named_scope("kv_update"):
+        # One-hot select instead of a batched scatter: GSPMD cannot partition a
+        # scatter whose index vector spans a sharded batch dim (it replicated
+        # the updates with a collective-permute + all-gather pair per cache,
+        # per layer, per step), while this jnp.where is elementwise — fully
+        # local under the slot-sharded serving cache layout. Selection is
+        # bit-exact (no arithmetic on cache values).
+        write_hot = (jnp.arange(s_cache)[None, :] == write_idx[:, None]
+                     )[:, :, None, None]                       # (B, S, 1, 1)
+        k_cache = jnp.where(write_hot, k[:, :1].astype(k_cache.dtype), k_cache)
+        v_cache = jnp.where(write_hot, v[:, :1].astype(v_cache.dtype), v_cache)
 
-    # Grouped attention WITHOUT materializing the GQA-expanded cache
-    # (a repeat would cost groups x the cache bytes — §Perf iteration 2):
-    # q: (B, KV, G, hd) against cache (B, S, KV, hd).
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q[:, 0].reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
-    logits = jnp.einsum("bngd,bsnd->bngs", qg,
-                        k_cache.astype(x.dtype)).astype(jnp.float32) * scale
+    with jax.named_scope("attention"):
+        # Grouped attention WITHOUT materializing the GQA-expanded cache
+        # (a repeat would cost groups x the cache bytes — §Perf iteration 2):
+        # q: (B, KV, G, hd) against cache (B, S, KV, hd).
+        groups = cfg.n_heads // cfg.n_kv_heads
+        qg = q[:, 0].reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
+        scale = cfg.head_dim ** -0.5
+        logits = jnp.einsum("bngd,bsnd->bngs", qg,
+                            k_cache.astype(x.dtype)).astype(jnp.float32) * scale
 
-    slots = jnp.arange(s_cache)[None, :]                       # (1, S)
-    if cfg.sliding_window:
-        # slot holds token (pos - age); valid if age < min(window, pos+1)
-        age = (write_idx[:, None] - slots) % s_cache
-        valid = age < jnp.minimum(jnp.int32(cfg.sliding_window), pos[:, None] + 1)
-    else:
-        valid = slots <= pos[:, None]
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bngs,bsnd->bngd", probs, v_cache.astype(x.dtype))
-    out = out.reshape(b, 1, cfg.q_dim)
-    return apply_linear(params["w_o"], out, sh=sh, kind="btd"), k_cache, v_cache
+        slots = jnp.arange(s_cache)[None, :]                       # (1, S)
+        if cfg.sliding_window:
+            # slot holds token (pos - age); valid if age < min(window, pos+1)
+            age = (write_idx[:, None] - slots) % s_cache
+            valid = age < jnp.minimum(jnp.int32(cfg.sliding_window), pos[:, None] + 1)
+        else:
+            valid = slots <= pos[:, None]
+        logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bngs,bsnd->bngd", probs, v_cache.astype(x.dtype))
+        out = out.reshape(b, 1, cfg.q_dim)
+        out = apply_linear(params["w_o"], out, sh=sh, kind="btd")
+    return out, k_cache, v_cache
 
 
 def chunk_attention(cfg, params, x, k_cache, v_cache, slot, offset, sh=None):
@@ -240,65 +244,68 @@ def chunk_attention(cfg, params, x, k_cache, v_cache, slot, offset, sh=None):
     the chunk size). Returns (out, k_cache, v_cache)."""
     _, c, _ = x.shape
     s_cache = k_cache.shape[1]
-    qkv = apply_linear(params["w_qkv"], x, params.get("b_qkv"),
-                       sh=sh, kind="qkv")
-    q, k, v = _split_qkv(cfg, qkv)                       # (1, C, H/KV, hd)
-    positions = offset + jnp.arange(c, dtype=jnp.int32)  # absolute positions
-    q = apply_rope(q, positions[None, :], cfg.rope_theta)
-    k = apply_rope(k, positions[None, :], cfg.rope_theta)
+    with jax.named_scope("attention"):
+        qkv = apply_linear(params["w_qkv"], x, params.get("b_qkv"),
+                           sh=sh, kind="qkv")
+        q, k, v = _split_qkv(cfg, qkv)                       # (1, C, H/KV, hd)
+        positions = offset + jnp.arange(c, dtype=jnp.int32)  # absolute positions
+        q = apply_rope(q, positions[None, :], cfg.rope_theta)
+        k = apply_rope(k, positions[None, :], cfg.rope_theta)
 
-    # the slot's pre-write cache rows (1, S_cache, KV, hd)
-    k_ctx = jax.lax.dynamic_slice_in_dim(k_cache, slot, 1, axis=0)
-    v_ctx = jax.lax.dynamic_slice_in_dim(v_cache, slot, 1, axis=0)
+        # the slot's pre-write cache rows (1, S_cache, KV, hd)
+        k_ctx = jax.lax.dynamic_slice_in_dim(k_cache, slot, 1, axis=0)
+        v_ctx = jax.lax.dynamic_slice_in_dim(v_cache, slot, 1, axis=0)
 
-    # Grouped attention without GQA-expanding the cache (same trick as
-    # decode_attention): q -> (1, C, KV, G, hd) against (1, S+C, KV, hd).
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(1, c, cfg.n_kv_heads, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
-    k_all = jnp.concatenate([k_ctx.astype(x.dtype), k.astype(x.dtype)], axis=1)
-    v_all = jnp.concatenate([v_ctx.astype(x.dtype), v.astype(x.dtype)], axis=1)
-    logits = jnp.einsum("bcngd,bsnd->bngcs", qg,
-                        k_all).astype(jnp.float32) * scale
+        # Grouped attention without GQA-expanding the cache (same trick as
+        # decode_attention): q -> (1, C, KV, G, hd) against (1, S+C, KV, hd).
+        groups = cfg.n_heads // cfg.n_kv_heads
+        qg = q.reshape(1, c, cfg.n_kv_heads, groups, cfg.head_dim)
+        scale = cfg.head_dim ** -0.5
+        k_all = jnp.concatenate([k_ctx.astype(x.dtype), k.astype(x.dtype)], axis=1)
+        v_all = jnp.concatenate([v_ctx.astype(x.dtype), v.astype(x.dtype)], axis=1)
+        logits = jnp.einsum("bcngd,bsnd->bngcs", qg,
+                            k_all).astype(jnp.float32) * scale
 
-    qi = jnp.arange(c, dtype=jnp.int32)
-    si = jnp.arange(s_cache, dtype=jnp.int32)
-    p_q = offset + qi                                    # (C,)
-    if cfg.sliding_window:
-        # ring slot s holds token t_s = (offset-1) - ((offset-1-s) % S);
-        # negative t_s means the slot was never written for this prefix
-        t_s = (offset - 1) - ((offset - 1 - si) % s_cache)
-        ctx_valid = ((t_s[None, :] >= 0)
-                     & (p_q[:, None] - t_s[None, :] < cfg.sliding_window))
-    else:
-        ctx_valid = jnp.broadcast_to(si[None, :] < offset, (c, s_cache))
-    chunk_valid = qi[None, :] <= qi[:, None]
-    if cfg.sliding_window:
-        chunk_valid &= (qi[:, None] - qi[None, :]) < cfg.sliding_window
-    valid = jnp.concatenate([ctx_valid, chunk_valid], axis=1)  # (C, S+C)
-    logits = jnp.where(valid[None, None, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bngcs,bsnd->bcngd", probs, v_all)
-    out = out.reshape(1, c, cfg.q_dim)
+        qi = jnp.arange(c, dtype=jnp.int32)
+        si = jnp.arange(s_cache, dtype=jnp.int32)
+        p_q = offset + qi                                    # (C,)
+        if cfg.sliding_window:
+            # ring slot s holds token t_s = (offset-1) - ((offset-1-s) % S);
+            # negative t_s means the slot was never written for this prefix
+            t_s = (offset - 1) - ((offset - 1 - si) % s_cache)
+            ctx_valid = ((t_s[None, :] >= 0)
+                         & (p_q[:, None] - t_s[None, :] < cfg.sliding_window))
+        else:
+            ctx_valid = jnp.broadcast_to(si[None, :] < offset, (c, s_cache))
+        chunk_valid = qi[None, :] <= qi[:, None]
+        if cfg.sliding_window:
+            chunk_valid &= (qi[:, None] - qi[None, :]) < cfg.sliding_window
+        valid = jnp.concatenate([ctx_valid, chunk_valid], axis=1)  # (C, S+C)
+        logits = jnp.where(valid[None, None, None], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bngcs,bsnd->bcngd", probs, v_all)
+        out = out.reshape(1, c, cfg.q_dim)
+        out = apply_linear(params["w_o"], out, sh=sh, kind="btd")
 
-    # post-attention write of the chunk's K/V into the slot's rows
-    kc = k.astype(k_cache.dtype)
-    vc = v.astype(v_cache.dtype)
-    if cfg.sliding_window:
-        # ring: chunk token j lands at slot (offset + j) % S_cache; with
-        # C <= S_cache every chunk token gets a distinct slot, and slots
-        # not addressed by the chunk keep their previous occupant
-        i_for_s = (si - offset) % s_cache
-        sel = (i_for_s < c)[None, :, None, None]
-        gather = jnp.minimum(i_for_s, c - 1)
-        k_row = jnp.where(sel, jnp.take(kc, gather, axis=1), k_ctx)
-        v_row = jnp.where(sel, jnp.take(vc, gather, axis=1), v_ctx)
-    else:
-        k_row = jax.lax.dynamic_update_slice(k_ctx, kc, (0, offset, 0, 0))
-        v_row = jax.lax.dynamic_update_slice(v_ctx, vc, (0, offset, 0, 0))
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_row, slot, axis=0)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_row, slot, axis=0)
-    return apply_linear(params["w_o"], out, sh=sh, kind="btd"), k_cache, v_cache
+    with jax.named_scope("kv_update"):
+        # post-attention write of the chunk's K/V into the slot's rows
+        kc = k.astype(k_cache.dtype)
+        vc = v.astype(v_cache.dtype)
+        if cfg.sliding_window:
+            # ring: chunk token j lands at slot (offset + j) % S_cache; with
+            # C <= S_cache every chunk token gets a distinct slot, and slots
+            # not addressed by the chunk keep their previous occupant
+            i_for_s = (si - offset) % s_cache
+            sel = (i_for_s < c)[None, :, None, None]
+            gather = jnp.minimum(i_for_s, c - 1)
+            k_row = jnp.where(sel, jnp.take(kc, gather, axis=1), k_ctx)
+            v_row = jnp.where(sel, jnp.take(vc, gather, axis=1), v_ctx)
+        else:
+            k_row = jax.lax.dynamic_update_slice(k_ctx, kc, (0, offset, 0, 0))
+            v_row = jax.lax.dynamic_update_slice(v_ctx, vc, (0, offset, 0, 0))
+        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_row, slot, axis=0)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_row, slot, axis=0)
+    return out, k_cache, v_cache
 
 
 def cache_length(cfg, seq_len: int) -> int:

@@ -27,11 +27,12 @@ def mlp(cfg, params: dict, x: jax.Array, sh=None) -> jax.Array:
     # activation constraints ride through the dispatch seam (sh/kind on
     # apply_linear), so packed / xnor serving leaves get the same TP layout
     # as the dense path
-    if "w_gate" in params:
-        g = apply_linear(params["w_gate"], x, sh=sh, kind="btf")
-        u = apply_linear(params["w_up"], x, sh=sh, kind="btf")
-        h = jax.nn.silu(g) * u
-        return apply_linear(params["w_down"], h, sh=sh, kind="btd")
-    h = apply_linear(params["wi"], x, sh=sh, kind="btf")
-    h = jax.nn.gelu(h)
-    return apply_linear(params["wo"], h, sh=sh, kind="btd")
+    with jax.named_scope("mlp"):
+        if "w_gate" in params:
+            g = apply_linear(params["w_gate"], x, sh=sh, kind="btf")
+            u = apply_linear(params["w_up"], x, sh=sh, kind="btf")
+            h = jax.nn.silu(g) * u
+            return apply_linear(params["w_down"], h, sh=sh, kind="btd")
+        h = apply_linear(params["wi"], x, sh=sh, kind="btf")
+        h = jax.nn.gelu(h)
+        return apply_linear(params["wo"], h, sh=sh, kind="btd")

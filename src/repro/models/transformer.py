@@ -136,13 +136,14 @@ def _embed_in(cfg, params, tokens_or_embeds, sh):
 
 
 def _head_out(cfg, params, x, sh):
-    x = rms_norm(x, params["final_norm"]["scale"])
-    if cfg.tie_embeddings:
-        w = params["embed"]["embedding"].astype(x.dtype).T
-    else:
-        w = params["lm_head"]["kernel"].astype(x.dtype)
-    logits = jnp.dot(x, w)
-    return sh.act(logits, "btv") if sh is not None else logits
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"]["scale"])
+        if cfg.tie_embeddings:
+            w = params["embed"]["embedding"].astype(x.dtype).T
+        else:
+            w = params["lm_head"]["kernel"].astype(x.dtype)
+        logits = jnp.dot(x, w)
+        return sh.act(logits, "btv") if sh is not None else logits
 
 
 def _decode_head_out(cfg, params, x, sh):

@@ -1,16 +1,17 @@
 """Serving observability: tracing, metrics, and the static collective audit.
 
-The sharded-serving hunt (ROADMAP: 86 tok/s sharded vs 316 single-device),
-the pipeline-plan work and the autotuner all need *measured feedback*;
-this package is the one place the serving stack reports itself.
+Performance work on the serving path needs *measured feedback*; this
+package is the one place the serving stack reports itself.
 
 Module map::
 
-    trace.py        Tracer — low-overhead span API threaded through
-                    ServeEngine.prefill_into / decode_step / stream_serve
-                    and the SlotBatcher refill path; host vs device time
-                    split via block_until_ready fencing (only while
-                    tracing); Chrome trace-event JSON export viewable in
+    trace.py        Tracer — low-overhead span API threaded through the
+                    ServeEngine entry points, stream_serve and the
+                    SlotBatcher refill path; each enabled span is also a
+                    jax.profiler.TraceAnnotation, so a profiler trace
+                    carries the program's spans beside the device ops
+                    (device time comes from that trace: the tracer never
+                    blocks); Chrome trace-event JSON export viewable in
                     Perfetto; validate_trace / `python -m repro.obs.trace`
                     schema + span-coverage checker (CI runs it).
     metrics.py      MetricsRegistry — process-local counters / gauges /

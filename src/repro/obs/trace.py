@@ -1,29 +1,28 @@
 """Low-overhead step-level tracing for the serving stack.
 
-The sharded-serving slowdown (ROADMAP: 86 tok/s sharded vs 316 single-
-device) cannot be hunted without seeing *where* each decode step spends its
-time: host-side bookkeeping (refill, sampling, the batcher ledger), jitted
-dispatch, and device compute. A :class:`Tracer` records wall-clock spans
-through the ``ServeEngine`` entry points and the ``stream_serve`` loop and
-exports them as Chrome trace-event JSON — open the file at
-https://ui.perfetto.dev (or ``chrome://tracing``) and the serving timeline
-reads like a flame chart.
+A :class:`Tracer` records spans through the ``ServeEngine`` entry points
+and the ``stream_serve`` loop: host-side bookkeeping (arrivals, refill,
+sampling, the token sync, the batcher ledger) and the enqueue of each
+jitted program. Every enabled span is two things at once:
+
+* a ``jax.profiler.TraceAnnotation`` with the span's name and args, so
+  while a profiler trace is running the program's spans land on its host
+  plane, on the same clock as the device ops. Device time, device idle
+  time and what the host was doing during it come from that trace;
+* a complete event in memory, exported as Chrome trace-event JSON — open
+  the file at https://ui.perfetto.dev (or ``chrome://tracing``) and the
+  serving timeline reads like a flame chart.
 
 Design constraints, in order:
 
 * **Off means off.** ``tracer.span(...)`` on a disabled tracer returns one
-  shared no-op context manager — no allocation, no clock read, no event.
-  The serving hot loop pays a single attribute check per span site, and
-  ``jax.block_until_ready`` fencing *only* happens while tracing (the
-  normal async-dispatch pipeline is never serialized by a dormant tracer).
-* **Host vs device split.** jax dispatch returns before the device
-  finishes; a wall-clock span around a jitted call measures only dispatch.
-  When tracing, the engine brackets each jitted call with a ``dispatch``
-  span (call returns) and a ``device`` span (``tracer.fence`` =
-  ``block_until_ready``), so the trace separates Python overhead from
-  compute. Fencing serializes the pipeline, which can itself shift the
-  numbers — the trace is for *attribution*, the untraced benchmark for
-  *throughput*.
+  shared no-op context manager — no allocation, no clock read, no
+  ``TraceAnnotation``. The serving hot loop pays a single attribute check
+  per span site.
+* **Never block.** A span around a jitted call measures the host's
+  enqueue of the program, not its device time: the tracer never waits on
+  a device value, so the async dispatch pipeline it observes runs as it
+  does untraced.
 * **Valid Chrome trace events.** Every span is a complete event
   (``"ph": "X"``) with ``ts``/``dur`` in microseconds since the tracer's
   epoch, ``pid``/``tid``, and a ``depth`` arg (the span-stack depth at
@@ -32,8 +31,9 @@ Design constraints, in order:
   runnable as ``python -m repro.obs.trace out.json`` (CI does).
 
 Span taxonomy (see docs/OBSERVABILITY.md): ``stream_serve`` (root) >
-``init_decode`` / ``step`` > ``refill`` / ``prefill_into`` / ``sample`` /
-``record`` / ``decode_step`` > ``dispatch`` / ``device``.
+``init_decode`` / ``arrivals`` / ``step`` > ``refill`` / ``sample`` /
+``record`` / ``chunk`` / ``decode_step`` / ``decode_prefill`` / ... >
+``prefill_into`` / ``token_sync`` / ``decode_steps``.
 """
 from __future__ import annotations
 
@@ -60,9 +60,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records a complete ("X") event on exit."""
+    """One live span: a profiler annotation while open, and a complete
+    ("X") event recorded on exit."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "args", "t0", "depth", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -70,8 +71,12 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        tr = self.tracer
-        stack = tr._stack()
+        # imported here: a dormant tracer never imports jax
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation(self.name, **self.args)
+        self.annotation.__enter__()
+        stack = self.tracer._stack()
         self.depth = len(stack)
         stack.append(self)
         self.t0 = time.perf_counter()
@@ -79,6 +84,7 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self.annotation.__exit__(*exc)
         tr = self.tracer
         tr._stack().pop()
         args = dict(self.args)
@@ -95,15 +101,12 @@ class _Span:
 class Tracer:
     """Span recorder with Chrome trace-event export.
 
-    ``enabled=False`` builds a dormant tracer: every ``span``/``instant``/
-    ``fence`` call is a no-op (``span`` returns a shared null context
-    manager — asserted in tests). ``fence=False`` keeps spans but never
-    blocks on device values (dispatch-only timing)."""
+    ``enabled=False`` builds a dormant tracer: every ``span``/``instant``
+    call is a no-op (``span`` returns a shared null context manager, and
+    no ``TraceAnnotation`` is made — asserted in tests)."""
 
-    def __init__(self, enabled: bool = True, fence: bool = True,
-                 pid: Optional[int] = None):
+    def __init__(self, enabled: bool = True, pid: Optional[int] = None):
         self.enabled = enabled
-        self.fence_enabled = fence
         self.events: list[dict] = []
         self.pid = os.getpid() if pid is None else pid
         self._t0 = time.perf_counter()
@@ -113,7 +116,8 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **args):
         """Context manager timing one serving phase; ``args`` land in the
-        event's ``args`` dict (small JSON-able values only)."""
+        event's ``args`` dict and in the profiler annotation's stats
+        (small scalars only)."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args)
@@ -127,15 +131,6 @@ class Tracer:
             "ts": (time.perf_counter() - self._t0) * 1e6,
             "pid": self.pid, "tid": self._tid(), "args": args,
         })
-
-    def fence(self, value):
-        """``jax.block_until_ready(value)`` — but only while tracing, so a
-        dormant tracer never serializes the async dispatch pipeline."""
-        if self.enabled and self.fence_enabled:
-            import jax
-
-            jax.block_until_ready(value)
-        return value
 
     # -- bookkeeping -------------------------------------------------------
     def _tid(self) -> int:

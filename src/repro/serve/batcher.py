@@ -9,7 +9,9 @@ the returned streams. This is the standard continuous-batching scheme (vLLM
 et al.) restricted to a static shape, which is what pjit wants.
 
 The batcher is also the accounting ledger: every request records submit /
-first-token / completion wall times (TTFT and per-request latency) and its
+admission / first-token / completion wall times (queue wait, TTFT and
+per-request latency), the serving-loop iterations at which it got a slot
+and had its whole prompt in, the prompt chunks run for it, and its
 generated tokens, so serving throughput is derived from tokens *actually
 recorded* (``tokens_generated``), never from steps-times-batch arithmetic.
 
@@ -41,6 +43,16 @@ class Request:
     t_submit: float = 0.0         # wall time at submit()
     t_first: Optional[float] = None   # wall time of the first recorded token
     t_done: Optional[float] = None    # wall time of the last recorded token
+    # Admission ledger, always on: ``t_admit`` is stamped by refill() when
+    # the request gets a slot; stream_serve writes the loop iteration of
+    # that admission (``admit_step``), the iteration at which its whole
+    # prompt is in (``ready_step``) and the prompt chunks it ran for it
+    # (``prefill_chunks``: one for a whole-prompt prefill, fewer than
+    # prompt_len / chunk after a prefix-cache hit).
+    t_admit: Optional[float] = None
+    admit_step: Optional[int] = None
+    ready_step: Optional[int] = None
+    prefill_chunks: int = 0
     # Per-token ensemble uncertainty (only filled under K-replica serving —
     # repro.stoch): replica vote agreement and mean logit variance aligned
     # with ``generated``; ``abstained`` latches once any recorded token's
@@ -118,6 +130,7 @@ class SlotBatcher:
                                     tokens=len(r.generated))
             if self.slots[i] is None and self.queue:
                 self.slots[i] = self.queue.popleft()
+                self.slots[i].t_admit = time.perf_counter()
                 changed.append(i)
                 self.tracer.instant("slot_refill", uid=self.slots[i].uid,
                                     slot=i, queued=len(self.queue))

@@ -202,10 +202,10 @@ class ServeEngine:
         self.cfg = cfg
         self.mesh = mesh
         self.abstain_threshold = abstain_threshold
-        # Observability (repro.obs): spans around every jitted entry point,
-        # with a dispatch/device split via block_until_ready fencing. The
-        # default NULL_TRACER makes every span site a no-op — in particular
-        # no fencing, so the async dispatch pipeline is untouched.
+        # Observability (repro.obs): a span around every jitted entry point
+        # (the host's enqueue; device time comes from a profiler trace,
+        # whose host plane carries these spans). The default NULL_TRACER
+        # makes every span site a no-op.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._replicas = None
         if ensemble is not None:
@@ -613,22 +613,16 @@ class ServeEngine:
         if self._replicas is not None:
             rs = self._replicas
             with tr.span("prefill_into", slot=slot), self._mesh_ctx():
-                with tr.span("dispatch"):
-                    logits, agree, var, cache = self._ens_prefill_into(
-                        rs.stacked, rs.base, state.cache, state.logits,
-                        state.agreement, state.variance, prompt,
-                        jnp.int32(slot), state.context_len)
-                with tr.span("device"):
-                    tr.fence(logits)
+                logits, agree, var, cache = self._ens_prefill_into(
+                    rs.stacked, rs.base, state.cache, state.logits,
+                    state.agreement, state.variance, prompt,
+                    jnp.int32(slot), state.context_len)
             return dataclasses.replace(state, cache=cache, logits=logits,
                                        agreement=agree, variance=var)
         with tr.span("prefill_into", slot=slot), self._mesh_ctx():
-            with tr.span("dispatch"):
-                logits, cache = self._prefill_into(
-                    self.params, state.cache, state.logits, prompt,
-                    jnp.int32(slot), state.context_len)
-            with tr.span("device"):
-                tr.fence(logits)
+            logits, cache = self._prefill_into(
+                self.params, state.cache, state.logits, prompt,
+                jnp.int32(slot), state.context_len)
         return dataclasses.replace(state, cache=cache, logits=logits)
 
     def decode_step(self, state: DecodeState, tokens) -> DecodeState:
@@ -640,21 +634,14 @@ class ServeEngine:
         if self._replicas is not None:
             rs = self._replicas
             with tr.span("decode_step"), self._mesh_ctx():
-                with tr.span("dispatch"):
-                    es, cache = self._decode_ens(rs.stacked, rs.base,
-                                                 state.cache, tokens)
-                with tr.span("device"):
-                    tr.fence(es.mean_logits)
+                es, cache = self._decode_ens(rs.stacked, rs.base,
+                                             state.cache, tokens)
             return dataclasses.replace(
                 state, cache=cache,
                 logits=es.mean_logits.astype(state.logits.dtype),
                 agreement=es.agreement, variance=es.variance)
         with tr.span("decode_step"), self._mesh_ctx():
-            with tr.span("dispatch"):
-                logits, cache = self._decode(self.params, state.cache,
-                                             tokens)
-            with tr.span("device"):
-                tr.fence(logits)
+            logits, cache = self._decode(self.params, state.cache, tokens)
         return dataclasses.replace(state, cache=cache, logits=logits)
 
     def decode_steps(self, state: DecodeState, d: int):
@@ -678,11 +665,8 @@ class ServeEngine:
                 "decodes one step at a time (stream_serve falls back)")
         tr = self.tracer
         with tr.span("decode_steps", d=d), self._mesh_ctx():
-            with tr.span("dispatch"):
-                cache, logits, toks = self._decode_chunk(
-                    self.params, state.cache, state.logits, int(d))
-            with tr.span("device"):
-                tr.fence(logits)
+            cache, logits, toks = self._decode_chunk(
+                self.params, state.cache, state.logits, int(d))
         return dataclasses.replace(state, cache=cache, logits=logits), toks
 
     # -- chunked prefill + prefix reuse ------------------------------------
@@ -703,12 +687,9 @@ class ServeEngine:
         toks = jnp.asarray(tokens, jnp.int32).reshape(1, -1)
         with tr.span("prefill_chunk", slot=slot, offset=int(offset),
                      c=int(toks.shape[1])), self._mesh_ctx():
-            with tr.span("dispatch"):
-                logits, cache = self._prefill_chunk(
-                    self.params, state.cache, state.logits, toks,
-                    jnp.int32(slot), jnp.int32(offset))
-            with tr.span("device"):
-                tr.fence(logits)
+            logits, cache = self._prefill_chunk(
+                self.params, state.cache, state.logits, toks,
+                jnp.int32(slot), jnp.int32(offset))
         return dataclasses.replace(state, cache=cache, logits=logits)
 
     def fused_step(self, state: DecodeState, tokens, keep_mask, slot: int,
@@ -726,12 +707,9 @@ class ServeEngine:
         toks = jnp.asarray(chunk_tokens, jnp.int32).reshape(1, -1)
         with tr.span("decode_prefill", slot=slot, offset=int(offset),
                      c=int(toks.shape[1])), self._mesh_ctx():
-            with tr.span("dispatch"):
-                logits, cache = self._decode_prefill(
-                    self.params, state.cache, state.logits, tokens, keep,
-                    toks, jnp.int32(slot), jnp.int32(offset))
-            with tr.span("device"):
-                tr.fence(logits)
+            logits, cache = self._decode_prefill(
+                self.params, state.cache, state.logits, tokens, keep,
+                toks, jnp.int32(slot), jnp.int32(offset))
         return dataclasses.replace(state, cache=cache, logits=logits)
 
     def capture_slot(self, state: DecodeState, slot: int):
@@ -760,12 +738,8 @@ class ServeEngine:
         one = {k: jnp.asarray(v) for k, v in cache_rows.items()}
         with tr.span("prefix_splice", slot=slot,
                      full=bool(use_lg)), self._mesh_ctx():
-            with tr.span("dispatch"):
-                logits, cache = self._splice(state.cache, state.logits,
-                                             one, lg, jnp.int32(slot),
-                                             use_lg)
-            with tr.span("device"):
-                tr.fence(logits)
+            logits, cache = self._splice(state.cache, state.logits, one,
+                                         lg, jnp.int32(slot), use_lg)
         return dataclasses.replace(state, cache=cache, logits=logits)
 
 
@@ -807,9 +781,14 @@ def stream_serve(engine: ServeEngine, batcher, *,
     K-replica ensemble serving fall back to the one-step loop.
 
     Observability: the engine's tracer (``ServeEngine(tracer=...)``) wraps
-    the whole loop in a ``stream_serve`` span with one ``step`` span per
+    the whole loop in a ``stream_serve`` span with an ``arrivals`` span
+    (around the hook, when one is given) and one ``step`` span per
     iteration (``refill`` / ``sample`` / ``record`` children; the engine
-    adds ``prefill_into`` / ``decode_step`` with dispatch/device splits).
+    adds ``prefill_into`` / ``decode_step`` / ...; every device->host
+    crossing of sampled tokens is a ``token_sync`` span). Each admitted
+    request's ledger entry gets the loop iteration (counted from 1) that
+    admitted it (``admit_step``), the one at which its whole prompt was in
+    (``ready_step``) and the prompt chunks run for it (``prefill_chunks``).
     Pass ``metrics`` (a ``repro.obs.MetricsRegistry``) to record per-step
     latency, queue depth and slot occupancy histograms, prefill/step/token
     counters, the request-ledger TTFT/latency histograms, and a
@@ -902,6 +881,7 @@ def stream_serve(engine: ServeEngine, batcher, *,
                              logits=lg if full else None)
         if full:
             batcher.mark_ready(slot)
+            req.ready_step = iterations
             del in_prefill[slot]
         else:
             in_prefill[slot] = new_off
@@ -919,8 +899,10 @@ def stream_serve(engine: ServeEngine, batcher, *,
             while True:
                 t_step = time.perf_counter()
                 iterations += 1
-                more_arrivals = (bool(arrivals(iterations))
-                                 if arrivals is not None else False)
+                more_arrivals = False
+                if arrivals is not None:
+                    with tr.span("arrivals"):
+                        more_arrivals = bool(arrivals(iterations))
                 with tr.span("step", step=steps):
                     with tr.span("refill"):
                         for slot in batcher.refill():
@@ -930,6 +912,7 @@ def stream_serve(engine: ServeEngine, batcher, *,
                                     f"request {req.uid} wants max_new="
                                     f"{req.max_new} but the decode state was "
                                     f"sized for max_new_cap={cap}")
+                            req.admit_step = iterations
                             if metrics is not None:
                                 metrics.counter(
                                     "serve_prefills_total",
@@ -938,6 +921,8 @@ def stream_serve(engine: ServeEngine, batcher, *,
                             if not use_prefill_chunks:
                                 state = engine.prefill_into(state, slot,
                                                             req.prompt)
+                                req.ready_step = iterations
+                                req.prefill_chunks = 1
                                 continue
                             off = 0
                             if prefix_cache is not None:
@@ -953,6 +938,8 @@ def stream_serve(engine: ServeEngine, batcher, *,
                             if off < batcher.prompt_len:
                                 batcher.mark_prefilling(slot)
                                 in_prefill[slot] = off
+                            else:
+                                req.ready_step = iterations
                     if metrics is not None:
                         queue_h.observe(len(batcher.queue))
                         occ_h.observe(
@@ -980,7 +967,8 @@ def stream_serve(engine: ServeEngine, batcher, *,
                                         / temperature, axis=-1)
                                 else:
                                     tok = jnp.argmax(state.logits, axis=-1)
-                                tok_host = np.asarray(tok)
+                                with tr.span("token_sync"):
+                                    tok_host = np.asarray(tok)
                             with tr.span("record"):
                                 batcher.record(tok_host)
                             steps += 1
@@ -996,6 +984,7 @@ def stream_serve(engine: ServeEngine, batcher, *,
                         else:
                             state = engine.prefill_chunk_into(
                                 state, slot, chunk_toks, off)
+                        req.prefill_chunks += 1
                         _advance_prefill(state, slot, off + c)
                         if metrics is not None:
                             metrics.counter("serve_prefill_chunks_total",
@@ -1012,7 +1001,8 @@ def stream_serve(engine: ServeEngine, batcher, *,
                             # the chunk's ONE host crossing (explicit, so a
                             # jax.transfer_guard around the steady state
                             # stays silent — asserted in tests)
-                            tok_chunk = jax.device_get(toks)
+                            with tr.span("token_sync"):
+                                tok_chunk = jax.device_get(toks)
                         with tr.span("record"):
                             for i in range(d):
                                 batcher.record(tok_chunk[:, i])
@@ -1038,14 +1028,16 @@ def stream_serve(engine: ServeEngine, batcher, *,
                                 / temperature, axis=-1)
                         else:
                             tok = jnp.argmax(state.logits, axis=-1)
-                        tok_host = np.asarray(tok)
+                        with tr.span("token_sync"):
+                            tok_host = np.asarray(tok)
                     with tr.span("record"):
                         if state.agreement is not None:
-                            agr = np.asarray(state.agreement)
+                            with tr.span("token_sync"):
+                                agr = np.asarray(state.agreement)
+                                var = np.asarray(state.variance)
                             thr = engine.abstain_threshold
                             batcher.record(
-                                tok_host, agreement=agr,
-                                variance=np.asarray(state.variance),
+                                tok_host, agreement=agr, variance=var,
                                 abstained=None if thr is None
                                 else agr < thr)
                         else:

@@ -106,16 +106,59 @@ class TestTracerDisabled:
         tr.instant("submit", uid=0)
         assert tr.events == []
 
-    def test_fence_passthrough_without_jax(self):
-        """Disabled fence returns the value untouched (and never blocks)."""
-        obj = object()
-        assert NULL_TRACER.fence(obj) is obj
-        assert Tracer(enabled=True, fence=False).fence(obj) is obj
+    def test_disabled_span_never_makes_a_profiler_annotation(
+            self, monkeypatch):
+        """A dormant tracer must not touch the profiler: with
+        ``TraceAnnotation`` made to raise, its spans still run."""
+        import jax
+
+        def boom(*a, **k):
+            raise AssertionError("TraceAnnotation made by a dormant tracer")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+        for tr in (NULL_TRACER, Tracer(enabled=False)):
+            s = tr.span("decode_prefill", slot=1, offset=0, c=8)
+            assert s is _NULL_SPAN
+            with s:
+                pass
+        with pytest.raises(AssertionError, match="dormant"):
+            with Tracer().span("step"):
+                pass
+
+
+class TestTracerAnnotations:
+    def test_enabled_span_opens_a_named_annotation(self, monkeypatch):
+        """Each enabled span is also a profiler annotation with its name
+        and args, entered and exited around the span's body."""
+        import jax
+
+        seen = []
+
+        class Recording:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                seen.append(("enter", self.name, self.args))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name, exc[0]))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+        tr = Tracer()
+        with tr.span("step", step=2):
+            with tr.span("token_sync"):
+                pass
+        assert seen == [("enter", "step", {"step": 2}),
+                        ("enter", "token_sync", {}),
+                        ("exit", "token_sync", None),
+                        ("exit", "step", None)]
+        assert [e["name"] for e in tr.events] == ["token_sync", "step"]
 
 
 class TestTracerEvents:
     def _traced(self):
-        tr = Tracer(fence=False, pid=7)
+        tr = Tracer(pid=7)
         with tr.span("root", cap=4):
             with tr.span("child", k=1):
                 time.sleep(0.002)
@@ -194,9 +237,19 @@ class TestTracedServing:
         batcher = SlotBatcher(2, 4, tracer=tracer)
         rng = np.random.default_rng(0)
         metrics = MetricsRegistry()
-        for _ in range(4):
-            batcher.submit(rng.integers(0, cfg.vocab_size, 4), 3)
-        steps = stream_serve(engine, batcher, max_new_cap=3, metrics=metrics)
+        prompts = [rng.integers(0, cfg.vocab_size, 4) for _ in range(4)]
+        for p in prompts[:2]:
+            batcher.submit(p, 3)
+
+        def arrivals(it):
+            # the other two arrive while the first two decode
+            if it == 2:
+                for p in prompts[2:]:
+                    batcher.submit(p, 3)
+            return it < 2
+
+        steps = stream_serve(engine, batcher, max_new_cap=3, metrics=metrics,
+                             arrivals=arrivals)
         return tracer, metrics, batcher, steps
 
     def test_trace_covers_serving_loop(self):
@@ -205,10 +258,16 @@ class TestTracedServing:
         assert info["root"] == "stream_serve"
         assert info["coverage"] >= 0.95   # the acceptance bar CI enforces
         names = {e["name"] for e in tracer.events}
-        assert {"stream_serve", "init_decode", "step", "refill",
-                "prefill_into", "decode_step", "dispatch", "device",
-                "sample", "record", "submit", "slot_refill",
+        assert {"stream_serve", "init_decode", "arrivals", "step", "refill",
+                "prefill_into", "decode_step", "sample", "token_sync",
+                "record", "submit", "slot_refill",
                 "request_done"} <= names
+        # no fencing: the tracer never splits an enqueue from device time
+        assert not names & {"dispatch", "device"}
+        depth = {e["name"]: e["args"]["depth"] for e in tracer.events
+                 if e.get("ph") == "X"}
+        assert depth["arrivals"] == depth["step"] == 1
+        assert depth["token_sync"] == 3          # step > sample > token_sync
 
         # ledger-derived metrics agree with the batcher ground truth
         assert metrics.counter("serve_steps_total").value == steps
@@ -221,6 +280,67 @@ class TestTracedServing:
         assert metrics.gauge("serve_tok_per_s").value > 0
         occ = metrics.histogram("serve_slot_occupancy")
         assert occ.count == steps and max(occ.samples) <= 1.0
+
+
+class TestProfilerTrace:
+    """The program's spans on the profiler's clock: a chunked-prefill
+    serving loop run under ``jax.profiler`` leaves its spans, with their
+    args as stats, on the host plane of the ``.xplane.pb``."""
+
+    def test_spans_land_on_the_host_plane(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.configs import base as cb
+        from repro.models import transformer as T
+        from repro.serve.batcher import SlotBatcher
+        from repro.serve.engine import ServeEngine, stream_serve
+
+        cfg = cb.get_config("starcoder2_3b", smoke=True)
+        params = T.init_lm(cfg, jax.random.key(0))
+        tracer = Tracer()
+        engine = ServeEngine(cfg, params, tracer=tracer)
+        batcher = SlotBatcher(2, 16)
+        rng = np.random.default_rng(1)
+        batcher.submit(rng.integers(1, cfg.vocab_size, 16), 4)
+        sent = []
+
+        def arrivals(it):
+            # a second prompt arrives while the first decodes, so its
+            # chunks run fused into decode steps (decode_prefill)
+            if not sent and batcher.active_mask().any():
+                batcher.submit(rng.integers(1, cfg.vocab_size, 16), 2)
+                sent.append(it)
+            return not sent
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            stream_serve(engine, batcher, max_new_cap=4, prefill_chunk=8,
+                         arrivals=arrivals)
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        host = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                for line in plane.lines:
+                    for e in line.events:
+                        host.setdefault(e.name, []).append(dict(e.stats))
+        assert {"stream_serve", "step", "arrivals", "refill", "sample",
+                "token_sync", "record", "decode_prefill",
+                "prefill_chunk"} <= set(host)
+        chunks = sorted((int(st["offset"]), int(st["c"]))
+                        for st in host["decode_prefill"])
+        assert chunks == [(0, 8), (8, 8)]
+        # the in-memory export saw the same spans
+        assert len(host["token_sync"]) == sum(
+            e["name"] == "token_sync" for e in tracer.events)
 
 
 class TestRecordRequestMetrics:

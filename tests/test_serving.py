@@ -92,6 +92,30 @@ class TestServeEngine:
             seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
 
 
+class TestStepScopes:
+    def test_decode_program_names_its_layers(self):
+        """The compiled decode step carries the model's named scopes in
+        its op metadata: the one-hot K/V cache write under ``kv_update``,
+        and ``attention``, ``mlp`` and ``lm_head`` around the rest."""
+        import re
+
+        cfg = cb.get_config("starcoder2_3b", smoke=True)
+        params = T.init_lm(cfg, jax.random.key(0))
+        engine = ServeEngine(cfg, params)
+        state = engine.init_decode(2, 8, 4)
+        text = engine._decode.lower(params, state.cache,
+                                    jnp.zeros((2, 1), jnp.int32)
+                                    ).compile().as_text()
+        # one layer's cache: (slots, positions, kv heads, head dim)
+        layer = ",".join(map(str, state.cache["k"].shape[1:]))
+        writes = [ln for ln in text.splitlines()
+                  if re.search(rf"\[{layer}\]\S* select\(", ln)
+                  and "/kv_update/" in ln]
+        assert len(writes) >= 2, "K and V cache writes"
+        for scope in ("attention", "mlp", "lm_head"):
+            assert f"/{scope}/" in text, scope
+
+
 class TestContinuousDecode:
     """Step-level continuous batching: the persistent slot-addressed cache
     must reproduce one-shot generation bit-for-bit."""
@@ -568,3 +592,57 @@ class TestChunkedPrefillServing:
         # earlier admissions' (prefill chunks never stamp t_first)
         t = {r.uid: r.t_first for r in b.completed}
         assert t[2] >= max(t[0], t[1])
+
+    @staticmethod
+    def _admission_ledger(n_requests):
+        """Serves ``n_requests`` prompts of 32 tokens in 8-token chunks,
+        all queued before the loop starts, and returns the ledger."""
+        cfg = cb.get_config("starcoder2_3b", smoke=True)
+        params = T.init_lm(cfg, jax.random.key(0))
+        engine = ServeEngine(cfg, params)
+        rng = np.random.default_rng(3)
+        b = SlotBatcher(n_slots=2, prompt_len=32)
+        for _ in range(n_requests):
+            b.submit(rng.integers(1, cfg.vocab_size, 32), 3)
+        stream_serve(engine, b, max_new_cap=3, prefill_chunk=8)
+        assert b.idle and len(b.completed) == n_requests
+        return sorted(b.completed, key=lambda r: r.uid)
+
+    def test_admission_ledger_of_a_lone_request(self):
+        """A lone prompt of four chunks runs them back to back: admitted
+        and ready four loop iterations apart, stamped in order."""
+        r, = self._admission_ledger(1)
+        assert r.prefill_chunks == 4
+        assert r.admit_step == 1
+        assert r.ready_step - r.admit_step + 1 == 4
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+
+    def test_admission_ledger_of_requests_admitted_together(self):
+        """Two prompts admitted in one iteration share the one chunk per
+        step: the second waits four steps behind the first, so the loop
+        spends 12 steps on 8 chunks (1.5 steps per chunk)."""
+        a, b = self._admission_ledger(2)
+        assert a.admit_step == b.admit_step
+        assert a.prefill_chunks == b.prefill_chunks == 4
+        assert (a.ready_step - a.admit_step + 1,
+                b.ready_step - b.admit_step + 1) == (4, 8)
+        steps = sum(r.ready_step - r.admit_step + 1 for r in (a, b))
+        assert steps / (a.prefill_chunks + b.prefill_chunks) == 1.5
+        assert a.t_admit <= b.t_admit <= b.t_first
+
+    def test_admission_ledger_of_whole_prompt_prefill(self):
+        """Without chunking a request's whole prompt goes in as one chunk,
+        at the iteration that admits it; a request that waited for a slot
+        is admitted later than it was submitted."""
+        cfg = cb.get_config("starcoder2_3b", smoke=True)
+        params = T.init_lm(cfg, jax.random.key(0))
+        engine = ServeEngine(cfg, params)
+        b = SlotBatcher(n_slots=1, prompt_len=4)
+        for _ in range(2):
+            b.submit(np.arange(1, 5), 2)
+        stream_serve(engine, b)
+        first, second = sorted(b.completed, key=lambda r: r.uid)
+        for r in (first, second):
+            assert r.prefill_chunks == 1 and r.ready_step == r.admit_step
+        assert first.admit_step == 1 and second.admit_step > 1
+        assert second.t_admit >= first.t_done
