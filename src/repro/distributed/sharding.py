@@ -77,9 +77,30 @@ class ShardCtx:
     mesh: Optional[Mesh] = None
     enable: bool = True
     decode: bool = False
+    #: the model code runs vmapped over a stack of replicas (the K-replica
+    #: ensemble), whose placement a ``shard_map`` inside cannot name
+    replicas: bool = False
 
     def _p(self, *spec) -> Optional[P]:
         return P(*spec)
+
+    def slot_split(self, n_slots: int):
+        """The data axes (one name or a tuple) over which the serving
+        layout splits ``n_slots`` cache slots, so that per-slot cache
+        writes can run per device under ``shard_map``; None where there is
+        no such split: no mesh or one device, the training layout, a
+        vmapped replica stack, or a slot count the data axes do not divide
+        (the cache then replicates)."""
+        if (not self.enable or not self.decode or self.replicas
+                or self.mesh is None or self.mesh.size == 1):
+            return None
+        dp = batch_axes(self.mesh)
+        n = 1
+        for a in dp:
+            n *= self.mesh.shape[a]
+        if n == 1 or n_slots % n:
+            return None
+        return dp if len(dp) > 1 else dp[0]
 
     def act(self, x: jax.Array, kind: str) -> jax.Array:
         """Applies a with_sharding_constraint for a logical activation kind."""

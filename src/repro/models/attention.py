@@ -8,6 +8,11 @@ replicated over "model" — so the per-step cache write, softmax and PV
 reduction all run device-local, and the block's cross-device traffic is one
 all-gather after the col-parallel qkv matmul plus one all-reduce for the
 row-parallel output projection).
+
+The decode and chunk paths take the whole stacked cache ``(L, B, S, KV,
+hd)``, which the layer scan carries, and a layer index: each layer writes
+only its new rows into the cache, in place (decode: one row per slot,
+before attention reads the layer; chunk: the slot's C rows, after).
 """
 from __future__ import annotations
 
@@ -170,15 +175,61 @@ def attention_with_cache_write(cfg, params, x, positions, sh=None):
     return apply_linear(params["w_o"], out, sh=sh, kind="btd"), k, v
 
 
-def decode_attention(cfg, params, x, k_cache, v_cache, pos, sh=None):
-    """One-token decode. x: (B, 1, D); caches: (B, S_cache, KV, hd);
-    pos: (B,) int32 current write position (tokens seen so far).
+def _write_rows(cache, rows, layer, idx, sh):
+    """``cache[layer, b, idx[b]] = rows[b]`` for every slot ``b``: one
+    scatter of B rows into the stacked (L, B, S, KV, hd) cache, in place.
+
+    GSPMD would replicate this scatter over slot-sharded caches (it cannot
+    see that row ``b`` lands in slot ``b``), so where the serving layout
+    splits the slots (``ShardCtx.slot_split``) it runs once per device
+    under ``shard_map``, on the device's own slots. (A scatter with the
+    slots as a batching dim stays local too, but XLA then relayouts the
+    whole cache around it.) Bit-exact: no arithmetic on cache values."""
+    def write(c, r, i, l):
+        return c.at[l, jnp.arange(c.shape[1]), i].set(r.astype(c.dtype))
+
+    dp = sh.slot_split(cache.shape[1]) if sh is not None else None
+    if dp is None:
+        return write(cache, rows, idx, layer)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        write, mesh=sh.mesh, in_specs=(P(None, dp), P(dp), P(dp), P()),
+        out_specs=P(None, dp), check_vma=False)(cache, rows, idx, layer)
+
+
+def _write_chunk(cfg, cache, rows, layer, slot, offset, sh):
+    """Write one slot's C chunk rows (C, KV, hd) of one layer into the
+    stacked cache, in place: at rows ``offset ..`` of a linear cache
+    (clamped, as ``dynamic_update_slice`` clamps), at ``(offset + j) % S``
+    of a ring (with C <= S every chunk token gets a distinct row; rows the
+    chunk does not address keep their previous occupant). Both stay on the
+    device that holds the slot where the data axes shard the slots; the
+    cache is pinned to its placement on both sides of the write, or GSPMD
+    may lay the carried cache out another way and gather it every layer."""
+    if sh is not None:
+        cache = sh.act(cache, "cache_kv")
+    rows = rows.astype(cache.dtype)
+    if cfg.sliding_window:
+        ring = (offset + jnp.arange(rows.shape[0], dtype=jnp.int32)
+                ) % cache.shape[2]
+        cache = cache.at[layer, slot, ring].set(rows)
+    else:
+        cache = jax.lax.dynamic_update_slice(cache, rows[None, None],
+                                             (layer, slot, offset, 0, 0))
+    return sh.act(cache, "cache_kv") if sh is not None else cache
+
+
+def decode_attention(cfg, params, x, k_cache, v_cache, layer, pos, sh=None):
+    """One-token decode. x: (B, 1, D); caches: the stacked (L, B, S_cache,
+    KV, hd) buffers, ``layer`` this layer's traced index into them; pos:
+    (B,) int32 current write position (tokens seen so far).
 
     For sliding-window archs the cache length is the window and writes wrap
     (ring buffer); masking is by *token age*, which is wrap-invariant.
-    Returns (out, k_cache, v_cache)."""
+    Returns (out, k_cache, v_cache) with the layer's new rows written."""
     b, _, _ = x.shape
-    s_cache = k_cache.shape[1]
+    s_cache = k_cache.shape[2]
     with jax.named_scope("attention"):
         # "qkv": under a decode ShardCtx this is the block's ONE gather — the
         # col-parallel qkv matmul's output replicates here, so the split /
@@ -191,18 +242,11 @@ def decode_attention(cfg, params, x, k_cache, v_cache, pos, sh=None):
 
     write_idx = pos % s_cache if cfg.sliding_window else jnp.minimum(pos, s_cache - 1)
     with jax.named_scope("kv_update"):
-        # One-hot select instead of a batched scatter: GSPMD cannot partition a
-        # scatter whose index vector spans a sharded batch dim (it replicated
-        # the updates with a collective-permute + all-gather pair per cache,
-        # per layer, per step), while this jnp.where is elementwise — fully
-        # local under the slot-sharded serving cache layout. Selection is
-        # bit-exact (no arithmetic on cache values).
-        write_hot = (jnp.arange(s_cache)[None, :] == write_idx[:, None]
-                     )[:, :, None, None]                       # (B, S, 1, 1)
-        k_cache = jnp.where(write_hot, k[:, :1].astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(write_hot, v[:, :1].astype(v_cache.dtype), v_cache)
+        k_cache = _write_rows(k_cache, k[:, 0], layer, write_idx, sh)
+        v_cache = _write_rows(v_cache, v[:, 0], layer, write_idx, sh)
 
     with jax.named_scope("attention"):
+        k_l, v_l = k_cache[layer], v_cache[layer]        # (B, S, KV, hd)
         # Grouped attention WITHOUT materializing the GQA-expanded cache
         # (a repeat would cost groups x the cache bytes — §Perf iteration 2):
         # q: (B, KV, G, hd) against cache (B, S, KV, hd).
@@ -210,7 +254,7 @@ def decode_attention(cfg, params, x, k_cache, v_cache, pos, sh=None):
         qg = q[:, 0].reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
         scale = cfg.head_dim ** -0.5
         logits = jnp.einsum("bngd,bsnd->bngs", qg,
-                            k_cache.astype(x.dtype)).astype(jnp.float32) * scale
+                            k_l.astype(x.dtype)).astype(jnp.float32) * scale
 
         slots = jnp.arange(s_cache)[None, :]                       # (1, S)
         if cfg.sliding_window:
@@ -221,29 +265,31 @@ def decode_attention(cfg, params, x, k_cache, v_cache, pos, sh=None):
             valid = slots <= pos[:, None]
         logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bngs,bsnd->bngd", probs, v_cache.astype(x.dtype))
+        out = jnp.einsum("bngs,bsnd->bngd", probs, v_l.astype(x.dtype))
         out = out.reshape(b, 1, cfg.q_dim)
         out = apply_linear(params["w_o"], out, sh=sh, kind="btd")
     return out, k_cache, v_cache
 
 
-def chunk_attention(cfg, params, x, k_cache, v_cache, slot, offset, sh=None):
+def chunk_attention(cfg, params, x, k_cache, v_cache, layer, slot, offset,
+                    sh=None):
     """Chunked-prefill attention: C prompt tokens of ONE slot against the
-    slot-addressed cache. x: (1, C, D); caches: (n_slots, S_cache, KV, hd);
-    slot / offset are traced int32 scalars, ``offset`` = tokens already
-    prefilled into the slot.
+    slot-addressed cache. x: (1, C, D); caches: the stacked (L, n_slots,
+    S_cache, KV, hd) buffers, ``layer`` this layer's index into them;
+    layer / slot / offset are traced int32 scalars, ``offset`` = tokens
+    already prefilled into the slot.
 
     The chunk's queries attend over [pre-write cache rows ++ the chunk's
     own K/V] with one softmax, so a partially-prefilled slot sees exactly
     the tokens a whole-prompt prefill would: cache lanes are masked to the
     real pre-offset tokens (by token age for ring caches), chunk lanes are
     causal within the chunk (+ window). The chunk's K/V are written to the
-    slot's ring/linear positions only AFTER attention — writing first
-    would evict ring tokens still inside earlier in-chunk queries'
-    windows. Ring caches therefore require C <= S_cache (the engine clamps
-    the chunk size). Returns (out, k_cache, v_cache)."""
+    slot's ring/linear rows only AFTER attention — writing first would
+    evict ring tokens still inside earlier in-chunk queries' windows. Ring
+    caches therefore require C <= S_cache (the engine clamps the chunk
+    size). Returns (out, k_cache, v_cache)."""
     _, c, _ = x.shape
-    s_cache = k_cache.shape[1]
+    s_cache = k_cache.shape[2]
     with jax.named_scope("attention"):
         qkv = apply_linear(params["w_qkv"], x, params.get("b_qkv"),
                            sh=sh, kind="qkv")
@@ -253,8 +299,8 @@ def chunk_attention(cfg, params, x, k_cache, v_cache, slot, offset, sh=None):
         k = apply_rope(k, positions[None, :], cfg.rope_theta)
 
         # the slot's pre-write cache rows (1, S_cache, KV, hd)
-        k_ctx = jax.lax.dynamic_slice_in_dim(k_cache, slot, 1, axis=0)
-        v_ctx = jax.lax.dynamic_slice_in_dim(v_cache, slot, 1, axis=0)
+        k_ctx = jax.lax.dynamic_slice_in_dim(k_cache[layer], slot, 1)
+        v_ctx = jax.lax.dynamic_slice_in_dim(v_cache[layer], slot, 1)
 
         # Grouped attention without GQA-expanding the cache (same trick as
         # decode_attention): q -> (1, C, KV, G, hd) against (1, S+C, KV, hd).
@@ -288,23 +334,8 @@ def chunk_attention(cfg, params, x, k_cache, v_cache, slot, offset, sh=None):
         out = apply_linear(params["w_o"], out, sh=sh, kind="btd")
 
     with jax.named_scope("kv_update"):
-        # post-attention write of the chunk's K/V into the slot's rows
-        kc = k.astype(k_cache.dtype)
-        vc = v.astype(v_cache.dtype)
-        if cfg.sliding_window:
-            # ring: chunk token j lands at slot (offset + j) % S_cache; with
-            # C <= S_cache every chunk token gets a distinct slot, and slots
-            # not addressed by the chunk keep their previous occupant
-            i_for_s = (si - offset) % s_cache
-            sel = (i_for_s < c)[None, :, None, None]
-            gather = jnp.minimum(i_for_s, c - 1)
-            k_row = jnp.where(sel, jnp.take(kc, gather, axis=1), k_ctx)
-            v_row = jnp.where(sel, jnp.take(vc, gather, axis=1), v_ctx)
-        else:
-            k_row = jax.lax.dynamic_update_slice(k_ctx, kc, (0, offset, 0, 0))
-            v_row = jax.lax.dynamic_update_slice(v_ctx, vc, (0, offset, 0, 0))
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_row, slot, axis=0)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_row, slot, axis=0)
+        k_cache = _write_chunk(cfg, k_cache, k[0], layer, slot, offset, sh)
+        v_cache = _write_chunk(cfg, v_cache, v[0], layer, slot, offset, sh)
     return out, k_cache, v_cache
 
 

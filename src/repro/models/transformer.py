@@ -389,6 +389,14 @@ def cache_keep(cfg, old: dict, new: dict, keep) -> dict:
     return out
 
 
+def _layer_ids(cache):
+    """Scan input of layer indices into the stacked K/V cache. The cache
+    itself rides in the scan carry, so each layer writes its new rows into
+    the one buffer in place, where a cache passed as scan input and output
+    would be restacked, every layer's slab into a fresh buffer."""
+    return jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+
+
 def _set_pos(pos, slot, value):
     upd = jnp.reshape(value, (1,)).astype(pos.dtype)
     return jax.lax.dynamic_update_slice_in_dim(pos, upd, slot, axis=0)
@@ -441,10 +449,11 @@ def prefill_chunk(cfg, params, cache: dict, tokens, slot, offset, sh=None):
         return _hybrid_prefill_chunk(cfg, params, cache, x, slot, offset,
                                      new_pos, sh)
 
-    def body(x, xs):
-        lp, kc, vc = xs
+    def body(carry, xs):
+        x, kc, vc = carry
+        lp, layer = xs
         h = rms_norm(x, lp["ln1"]["scale"])
-        y, kc, vc = A.chunk_attention(cfg, lp["attn"], h, kc, vc,
+        y, kc, vc = A.chunk_attention(cfg, lp["attn"], h, kc, vc, layer,
                                       slot, offset, sh)
         x = x + y
         h = rms_norm(x, lp["ln2"]["scale"])
@@ -452,10 +461,11 @@ def prefill_chunk(cfg, params, cache: dict, tokens, slot, offset, sh=None):
             y, _ = MOE.moe_ffn(cfg, lp["moe"], h, sh)
         else:
             y = M.mlp(cfg, lp["mlp"], h, sh)
-        return x + y, (kc, vc)
+        return (x + y, kc, vc), ()
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], _layer_ids(cache)))
     new_cache = dict(cache, k=new_k, v=new_v, pos=new_pos)
     return _decode_head_out(cfg, params, x[:, -1:], sh), new_cache
 
@@ -465,8 +475,9 @@ def _hybrid_prefill_chunk(cfg, params, cache, x, slot, offset, new_pos, sh):
     attn_at = per // 2
     c = x.shape[1]
 
-    def body(x, xs):
-        lp, kc, vc, stc, cvc = xs
+    def body(carry, xs):
+        x, kc, vc = carry
+        lp, layer, stc, cvc = xs
         st_s = jax.lax.dynamic_slice_in_dim(stc, slot, 1, axis=1)
         cv_s = jax.lax.dynamic_slice_in_dim(cvc, slot, 1, axis=1)
         # fresh prefill (offset == 0): stale occupant state reads as zeros
@@ -478,7 +489,7 @@ def _hybrid_prefill_chunk(cfg, params, cache, x, slot, offset, new_pos, sh):
             h = rms_norm(x, lp["ln1"]["scale"][j])
             if j == attn_at:
                 y, kc, vc = A.chunk_attention(cfg, lp["attn"], h, kc, vc,
-                                              slot, offset, sh)
+                                              layer, slot, offset, sh)
             else:
                 mamba_j = jax.tree.map(lambda a, i=mi: a[i], lp["mamba"])
                 y, st, cv = S.ssm_forward(cfg, mamba_j, h, sh, chunk=c,
@@ -503,11 +514,11 @@ def _hybrid_prefill_chunk(cfg, params, cache, x, slot, offset, new_pos, sh):
             stc, jnp.stack(new_st).astype(stc.dtype), slot, axis=1)
         cvc = jax.lax.dynamic_update_slice_in_dim(
             cvc, jnp.stack(new_cv).astype(cvc.dtype), slot, axis=1)
-        return x, (kc, vc, stc, cvc)
+        return (x, kc, vc), (stc, cvc)
 
-    x, (nk, nv, nst, ncv) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"],
-                  cache["ssm"], cache["conv"]))
+    (x, nk, nv), (nst, ncv) = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], _layer_ids(cache), cache["ssm"], cache["conv"]))
     new_cache = dict(cache, k=nk, v=nv, ssm=nst, conv=ncv, pos=new_pos)
     return _decode_head_out(cfg, params, x[:, -1:], sh), new_cache
 
@@ -534,20 +545,23 @@ def decode_step(cfg, params, cache: dict, tokens_or_embeds, sh=None):
     if cfg.is_hybrid:
         return _hybrid_decode(cfg, params, cache, x, sh)
 
-    def body(x, xs):
-        lp, kc, vc = xs
+    def body(carry, xs):
+        x, kc, vc = carry
+        lp, layer = xs
         h = rms_norm(x, lp["ln1"]["scale"])
-        y, kc, vc = A.decode_attention(cfg, lp["attn"], h, kc, vc, pos, sh)
+        y, kc, vc = A.decode_attention(cfg, lp["attn"], h, kc, vc, layer,
+                                       pos, sh)
         x = x + y
         h = rms_norm(x, lp["ln2"]["scale"])
         if "moe" in lp:
             y, _ = MOE.moe_ffn(cfg, lp["moe"], h, sh)
         else:
             y = M.mlp(cfg, lp["mlp"], h, sh)
-        return x + y, (kc, vc)
+        return (x + y, kc, vc), ()
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], _layer_ids(cache)))
     new_cache = dict(cache, k=new_k, v=new_v, pos=pos + 1)
     return _decode_head_out(cfg, params, x, sh), new_cache
 
@@ -557,14 +571,16 @@ def _hybrid_decode(cfg, params, cache, x, sh):
     attn_at = per // 2
     pos = cache["pos"]
 
-    def body(x, xs):
-        lp, kc, vc, stc, cvc = xs
+    def body(carry, xs):
+        x, kc, vc = carry
+        lp, layer, stc, cvc = xs
         mi = di = oi = 0
         new_st, new_cv = [], []
         for j in range(per):
             h = rms_norm(x, lp["ln1"]["scale"][j])
             if j == attn_at:
-                y, kc, vc = A.decode_attention(cfg, lp["attn"], h, kc, vc, pos, sh)
+                y, kc, vc = A.decode_attention(cfg, lp["attn"], h, kc, vc,
+                                               layer, pos, sh)
             else:
                 mamba_j = jax.tree.map(lambda a, i=mi: a[i], lp["mamba"])
                 y, st, cv = S.ssm_decode_step(cfg, mamba_j, h, stc[mi], cvc[mi])
@@ -582,11 +598,11 @@ def _hybrid_decode(cfg, params, cache, x, sh):
                 y = M.mlp(cfg, mlp_j, h, sh)
                 di += 1
             x = x + y
-        return x, (kc, vc, jnp.stack(new_st), jnp.stack(new_cv))
+        return (x, kc, vc), (jnp.stack(new_st), jnp.stack(new_cv))
 
-    x, (nk, nv, nst, ncv) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"],
-                  cache["ssm"], cache["conv"]))
+    (x, nk, nv), (nst, ncv) = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], _layer_ids(cache), cache["ssm"], cache["conv"]))
     new_cache = dict(cache, k=nk, v=nv, ssm=nst, conv=ncv, pos=pos + 1)
     return _decode_head_out(cfg, params, x, sh), new_cache
 

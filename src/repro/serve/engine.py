@@ -296,7 +296,9 @@ class ServeEngine:
 
         def _prefill_chunk(p, cache, logits, chunk_toks, slot, offset):
             """One prefill chunk for one slot, no decode (the ramp-up /
-            drain path when no other slot is actively decoding)."""
+            drain path when no other slot is actively decoding). Donated
+            like the decode programs: the chunk's rows land in the live
+            cache instead of a whole-cache copy."""
             lg, cache = T.prefill_chunk(cfg, p, cache, chunk_toks, slot,
                                         offset, sh)
             logits = jax.lax.dynamic_update_slice_in_dim(
@@ -304,7 +306,7 @@ class ServeEngine:
             cache, logits = self._pin_state(cache, logits)
             return logits, cache
 
-        self._prefill_chunk = _jit(_prefill_chunk)
+        self._prefill_chunk = _jit(_prefill_chunk, donate_argnums=(1, 2))
 
         def _decode_prefill(p, cache, logits, tok, keep, chunk_toks, slot,
                             offset):
@@ -419,9 +421,13 @@ class ServeEngine:
 
         self._prefill_ens = _jit(_ens_prefill, static_argnums=3)
 
+        sh_rep = (dataclasses.replace(sh, replicas=True) if sh is not None
+                  else None)
+
         def _ens_decode(stacked, base, cache, tok):
             def one(st, c):
-                return T.decode_step(cfg, _substitute(base, st), c, tok, sh)
+                return T.decode_step(cfg, _substitute(base, st), c, tok,
+                                     sh_rep)
 
             rep_lg, cache = jax.vmap(one, in_axes=(0, 0),
                                      axis_size=k)(stacked, cache)

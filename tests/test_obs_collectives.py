@@ -191,27 +191,26 @@ class TestGoldenShardedAudit:
     def test_decode_step_exact_counts(self, measured):
         """The headline numbers, asserted inline — after the decode-mode
         ShardCtx overhaul (replicated decode activations, model-free cache,
-        vocab-parallel tied embedding, deferred logits gather, one-hot
-        cache writes, outputs pinned to the init_decode placement;
-        docs/ARCHITECTURE.md §Decode-step collective budget)
-        a decode step runs 10 (det) / 18 (xnor) collectives, down from the
-        41 the seq-parallel training layout cost. All remaining traffic is
-        activation-sized: det is 8 per-layer all-gathers + the deferred
-        logits gather + the vocab-parallel embed-lookup all-reduce; xnor
-        swaps four of the gathers for exact integer popcount all-reduces
-        (row-parallel down-projections) and pays two extra gathers pinning
-        the fresh KV entries back to the model-replicated cache layout —
-        the price of steady-state == audited program (unpinned, GSPMD
-        retraced into a far slower second program)."""
+        vocab-parallel tied embedding, deferred logits gather, K/V rows
+        scattered per device into the carried cache, outputs pinned to the
+        init_decode placement; docs/ARCHITECTURE.md §Decode-step
+        collective budget) a decode step runs 10 (det) / 12 (xnor)
+        collectives, down from the 41 the seq-parallel training layout
+        cost. All remaining traffic is activation-sized: det is 8
+        per-layer all-gathers + the deferred logits gather + the
+        vocab-parallel embed-lookup all-reduce; xnor swaps four of the
+        gathers for exact integer popcount all-reduces (row-parallel
+        down-projections) and pays two collective-permutes splitting the
+        qkv output."""
         det = CollectiveAudit.from_json(measured["det"]["decode_step"])
         assert det.counts == {"all-gather": 9, "all-reduce": 1}
         assert det.total_count == 10
         assert det.bytes["all-gather"] == 10240.0
         assert det.bytes["all-reduce"] == 1024.0
         xnor = CollectiveAudit.from_json(measured["xnor"]["decode_step"])
-        assert xnor.counts == {"all-gather": 7, "all-reduce": 5,
-                               "collective-permute": 6}
-        assert xnor.total_count == 18
+        assert xnor.counts == {"all-gather": 5, "all-reduce": 5,
+                               "collective-permute": 2}
+        assert xnor.total_count == 12
         # no weight-sized traffic anywhere: the largest single transfer is
         # well under the 131072-byte tied-embedding table gather the old
         # layout paid every step

@@ -95,8 +95,10 @@ class TestServeEngine:
 class TestStepScopes:
     def test_decode_program_names_its_layers(self):
         """The compiled decode step carries the model's named scopes in
-        its op metadata: the one-hot K/V cache write under ``kv_update``,
-        and ``attention``, ``mlp`` and ``lm_head`` around the rest."""
+        its op metadata: the K/V row writes (scatters into the stacked
+        cache) under ``kv_update``, and ``attention``, ``mlp`` and
+        ``lm_head`` around the rest. No select over a layer's cache
+        remains: the write touches rows, never a whole layer."""
         import re
 
         cfg = cb.get_config("starcoder2_3b", smoke=True)
@@ -106,14 +108,108 @@ class TestStepScopes:
         text = engine._decode.lower(params, state.cache,
                                     jnp.zeros((2, 1), jnp.int32)
                                     ).compile().as_text()
-        # one layer's cache: (slots, positions, kv heads, head dim)
-        layer = ",".join(map(str, state.cache["k"].shape[1:]))
-        writes = [ln for ln in text.splitlines()
-                  if re.search(rf"\[{layer}\]\S* select\(", ln)
+        lines = text.splitlines()
+        writes = [ln for ln in lines
+                  if re.search(r" (scatter|dynamic-update-slice)\(", ln)
                   and "/kv_update/" in ln]
         assert len(writes) >= 2, "K and V cache writes"
+        # one layer's cache: (slots, positions, kv heads, head dim)
+        layer = ",".join(map(str, state.cache["k"].shape[1:]))
+        assert not [ln for ln in lines
+                    if re.search(rf"\[{layer}\]\S* select\(", ln)]
         for scope in ("attention", "mlp", "lm_head"):
             assert f"/{scope}/" in text, scope
+
+
+class TestInPlaceCacheWrite:
+    """The model step writes only its own K/V rows into the stacked cache:
+    after one decode step and after one prefill chunk, every other row is
+    bit-identical to before, and the written rows are the K/V the step
+    handed to the cache writer (recorded in an eager run)."""
+
+    FAMILIES = {"dense": ("starcoder2_3b", None),
+                "sliding_window": ("h2o_danube_3_4b", 6),
+                "hybrid": ("jamba_1_5_large", None)}
+
+    def _setup(self, family):
+        import dataclasses
+
+        arch, window = self.FAMILIES[family]
+        cfg = cb.get_config(arch, smoke=True)
+        if window is not None:
+            cfg = dataclasses.replace(cfg, sliding_window=window)
+        params = T.init_lm(cfg, jax.random.key(0))
+        cache = T.init_cache(cfg, 4, 12)
+        ks = jax.random.split(jax.random.key(1), 2)
+        for name, key in zip(("k", "v"), ks):   # no zero row hides a write
+            cache[name] = jax.random.normal(key, cache[name].shape,
+                                            cache[name].dtype)
+        return cfg, params, cache
+
+    @staticmethod
+    def _spy(monkeypatch):
+        """Record (layer index, rows) for every K/V write."""
+        from repro.models import attention as A
+
+        seen = []
+        rows_fn, chunk_fn = A._write_rows, A._write_chunk
+
+        def rows(cache, r, layer, idx, sh):
+            seen.append((int(layer), np.asarray(r)))
+            return rows_fn(cache, r, layer, idx, sh)
+
+        def chunk(cfg, cache, r, layer, slot, offset, sh):
+            seen.append((int(layer), np.asarray(r)))
+            return chunk_fn(cfg, cache, r, layer, slot, offset, sh)
+
+        monkeypatch.setattr(A, "_write_rows", rows)
+        monkeypatch.setattr(A, "_write_chunk", chunk)
+        return seen
+
+    @staticmethod
+    def _expect(cache, seen, at):
+        """Old cache with the recorded rows placed at ``at(layer)`` — the
+        (slots, rows) index pair each write addresses."""
+        want = {n: np.array(cache[n]) for n in ("k", "v")}
+        assert len(seen) == 2 * cache["k"].shape[0], "one K and one V " \
+            "write per attention layer"
+        for i, (layer, rows) in enumerate(seen):
+            want["kv"[i % 2]][(layer,) + at(layer)] = rows
+        return want
+
+    @pytest.mark.parametrize("family", list(FAMILIES))
+    def test_decode_step_writes_one_row_per_slot(self, family, monkeypatch):
+        cfg, params, cache = self._setup(family)
+        s_cache = cache["k"].shape[2]
+        pos = np.array([3, 7, 10, 5], np.int32)    # the ring wraps at 6
+        cache["pos"] = jnp.asarray(pos)
+        seen = self._spy(monkeypatch)
+        tok = jnp.array([[1], [2], [3], [4]], jnp.int32)
+        with jax.disable_jit():
+            _, new = T.decode_step(cfg, params, dict(cache), tok)
+        idx = pos % s_cache if cfg.sliding_window else np.minimum(
+            pos, s_cache - 1)
+        want = self._expect(cache, seen, lambda l: (np.arange(4), idx))
+        for n in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(new[n]), want[n], n)
+
+    @pytest.mark.parametrize("family", list(FAMILIES))
+    def test_chunk_writes_its_rows_only(self, family, monkeypatch):
+        cfg, params, cache = self._setup(family)
+        s_cache = cache["k"].shape[2]
+        slot, offset, c = 2, 4, 4          # rows 4..7: past 6, a ring wraps
+        seen = self._spy(monkeypatch)
+        toks = jnp.arange(1, c + 1, dtype=jnp.int32)[None]
+        with jax.disable_jit():
+            _, new = T.prefill_chunk(cfg, params, dict(cache), toks,
+                                     jnp.int32(slot), jnp.int32(offset))
+        rows = (offset + np.arange(c)) % s_cache
+        if cfg.sliding_window:
+            assert rows.tolist() == [4, 5, 0, 1]
+        want = self._expect(cache, seen, lambda l: (slot, rows))
+        for n in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(new[n]), want[n], n)
+        assert int(new["pos"][slot]) == offset + c
 
 
 class TestContinuousDecode:

@@ -5,7 +5,9 @@ checks that the kernel survives as a ``tpu_custom_call``: what the chip's
 compiler refuses — a block that breaks the TPU tiling rule, an op the vector
 unit lacks, more VMEM than a kernel may use — fails here, with no chip.
 Nothing runs, so nothing about results or time is checked (the kernel
-parity tests and ``chip_smoke.py`` do that).
+parity tests and ``chip_smoke.py`` do that). The serving programs that
+write the K/V cache are compiled whole, for the temporary memory the chip
+compiler gives them.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library, and every test worker
@@ -109,3 +111,45 @@ def test_patch_pack_vgg_widest_block(compile_text):
         lambda xp: patch_pack_pallas(xp, ksize=(3, 3), oh=4, ow=4),
         ((8, 6, 6, 512), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("program",
+                         ["_decode", "_decode_prefill", "_prefill_chunk"])
+def test_serving_program_writes_cache_in_place(one_chip, no_persistent_cache,
+                                               program):
+    """starcoder2-3b widths (4 of 30 layers, bf16), 32 slots x 4096
+    positions, cache donated: each program that writes the cache needs
+    less temporary memory than one layer's K, so no program copies or
+    rewrites the cache (a whole-cache rewrite held 0.94 GB here)."""
+    from repro.configs.base import ModelConfig
+    from repro.models import transformer as T
+    from repro.serve.engine import ServeEngine
+
+    cfg = ModelConfig(name="starcoder2-3b", family="dense", n_layers=4,
+                      d_model=D_MODEL, n_heads=24, n_kv_heads=2,
+                      head_dim=128, d_ff=D_FF, vocab_size=49152,
+                      mlp_type="gelu", dtype="bfloat16")
+    slots, ctx, chunk = 32, 4096, 256
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = abstract(jax.eval_shape(lambda: T.init_lm(
+        cfg, jax.random.key(0), dtype=jnp.bfloat16)))
+    cache = abstract(jax.eval_shape(lambda: T.init_cache(cfg, slots, ctx)))
+    engine = ServeEngine(cfg, params)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    logits = arg((slots, cfg.vocab_size), jnp.bfloat16)
+    args = {"_decode": (params, cache, arg((slots, 1))),
+            "_decode_prefill": (params, cache, logits, arg((slots, 1)),
+                                arg((slots,), jnp.bool_), arg((1, chunk)),
+                                arg(()), arg(())),
+            "_prefill_chunk": (params, cache, logits, arg((1, chunk)),
+                               arg(()), arg(()))}[program]
+    compiled = getattr(engine, program).lower(*args).compile()
+    layer_k_bytes = slots * ctx * cfg.n_kv_heads * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
