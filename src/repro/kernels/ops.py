@@ -14,9 +14,14 @@ import jax.numpy as jnp
 from repro.core.compat import ceil_to as _ceil_to, on_tpu as _on_tpu
 from repro.core.packing import PACK, pack_bits, pad_to_pack
 from repro.kernels import ref
-from repro.kernels.binary_matmul import binary_matmul_pallas
+from repro.kernels.binary_matmul import (MAX_BLOCK_M, PIECE,
+                                         binary_matmul_pallas)
 from repro.kernels.stoch_binarize import binarize_pack_pallas
 
+
+# Below this many multiply-adds (M * N * K) ``binary_matmul`` takes the jnp
+# reference: padding to whole kernel blocks would exceed the work.
+MIN_KERNEL_MACS = 128 * 128 * 512
 
 # Global default for the use_pallas dispatch (dry-runs lower the jnp
 # reference body off-TPU for clean HLO; real-TPU serving keeps the kernel).
@@ -33,9 +38,9 @@ def binary_matmul(
     w_packed: jax.Array,
     scale: jax.Array | None = None,
     *,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 512,
+    block_m: int | None = None,
+    block_n: int | None = None,
+    block_k: int | None = None,
     out_dtype=jnp.float32,
     use_pallas: bool | None = None,
     compute_dtype=None,
@@ -43,8 +48,9 @@ def binary_matmul(
     """``x @ unpack(w_packed) [* scale]`` for x of shape (..., K).
 
     Uses the Pallas kernel (interpret mode off-TPU) with padding to block
-    multiples; falls back to the jnp reference when padding overhead would
-    exceed the problem size (tiny shapes). ``compute_dtype`` defaults to the
+    multiples; falls back to the jnp reference below ``MIN_KERNEL_MACS``
+    (tiny shapes). Blocks left as None come from the padded shape
+    (``binary_matmul.choose_blocks``). ``compute_dtype`` defaults to the
     input dtype for f32 activations (numerical parity with the dense path)
     and bf16 otherwise (the MXU-native choice)."""
     if use_pallas is None:
@@ -65,9 +71,9 @@ def _binary_matmul(
     w_packed: jax.Array,
     scale: jax.Array | None = None,
     *,
-    block_m: int,
-    block_n: int,
-    block_k: int,
+    block_m: int | None,
+    block_n: int | None,
+    block_k: int | None,
     out_dtype,
     use_pallas: bool,
     compute_dtype=jnp.bfloat16,
@@ -78,20 +84,23 @@ def _binary_matmul(
         raise ValueError(f"K mismatch: x has K={kdim}, packed has {k32 * PACK}")
     x2 = x.reshape(-1, kdim)
     m = x2.shape[0]
-    # Tiny problems: blocking pads > 4x the work; use the reference.
-    if not use_pallas or m * n * kdim < block_m * block_n * block_k:
+    if not use_pallas or m * n * kdim < MIN_KERNEL_MACS:
         out = ref.binary_matmul_ref(x2, w_packed, scale, out_dtype=out_dtype,
                                     compute_dtype=compute_dtype)
         return out.reshape(*lead, n)
 
-    bm = min(block_m, _ceil_to(m, 8))
-    mp, np_, kp = _ceil_to(m, bm), _ceil_to(n, block_n), _ceil_to(kdim, block_k)
+    if block_m:
+        block_m = min(block_m, _ceil_to(m, 8))
+        mp = _ceil_to(m, block_m)
+    else:  # one M-block up to MAX_BLOCK_M rows, blocks of 256 above
+        mp = _ceil_to(m, 16 if m <= MAX_BLOCK_M else 256)
+    np_, kp = _ceil_to(n, block_n or 128), _ceil_to(kdim, block_k or PIECE)
     xp = jnp.pad(x2, ((0, mp - m), (0, kp - kdim)))
     wp = jnp.pad(w_packed, ((0, (kp - kdim) // PACK), (0, np_ - n)))
     sp = None if scale is None else jnp.pad(scale, (0, np_ - n))
     out = binary_matmul_pallas(
         xp, wp, sp,
-        block_m=bm, block_n=block_n, block_k=block_k,
+        block_m=block_m, block_n=block_n, block_k=block_k,
         compute_dtype=compute_dtype,
         out_dtype=out_dtype, interpret=not _on_tpu(),
     )

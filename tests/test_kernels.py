@@ -10,7 +10,7 @@ hypothesis = pytest.importorskip(
 st = pytest.importorskip("hypothesis.strategies")
 
 from repro.core import packing as P
-from repro.kernels import ops, ref
+from repro.kernels import binary_matmul as BM, ops, ref
 from repro.kernels.binary_matmul import binary_matmul_pallas
 from repro.kernels.stoch_binarize import binarize_pack_pallas
 
@@ -97,6 +97,134 @@ class TestBinaryMatmulKernel:
         want = ref.binary_matmul_ref(x, wp)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-2, atol=3e-2)
+
+
+# starcoder2-3b's four projections (K, N): qkv, o, up, down
+LM_KN = [(3072, 3584), (3072, 3072), (3072, 12288), (12288, 3072)]
+# (compute dtype, scaled): each (K, N) meets all four across its four M
+_MODES = [(jnp.bfloat16, True), (jnp.float32, False), (jnp.bfloat16, False),
+          (jnp.float32, True)]
+KERNEL_CASES = [
+    pytest.param(m, k, n, *_MODES[(i + j) % 4], 1,
+                 id=f"m{m}-{k}x{n}-{_MODES[(i + j) % 4][0].__name__}"
+                    f"{'-scaled' if _MODES[(i + j) % 4][1] else ''}")
+    for i, (k, n) in enumerate(LM_KN) for j, m in enumerate((16, 32, 256, 272))
+] + [
+    pytest.param(200, 544, 100, jnp.bfloat16, True, 1, id="ragged-bf16-scaled"),
+    pytest.param(40, 800, 300, jnp.float32, False, 1, id="ragged-f32"),
+    pytest.param(520, 512, 384, jnp.bfloat16, False, 1, id="m520-two-mblocks"),
+    pytest.param(16, 3072, 3584, jnp.bfloat16, True, 3, id="vmap-replicas-bf16"),
+    pytest.param(24, 1024, 640, jnp.float32, True, 2, id="vmap-replicas-f32"),
+]
+
+
+def _integer_x(key, shape, dtype):
+    """Small integer activations: every product and partial sum is an
+    integer below 2**24, exact in bf16 and f32 in any order, so the kernel
+    must equal the reference bit for bit."""
+    x = jnp.round(jax.random.normal(key, shape) * 3)
+    return jnp.clip(x, -8, 8).astype(dtype)
+
+
+@pytest.mark.parametrize("m,k,n,dtype,scaled,replicas", KERNEL_CASES)
+def test_binary_matmul_kernel_exact(m, k, n, dtype, scaled, replicas):
+    """ops.binary_matmul (interpret mode) == binary_matmul_ref exactly at
+    the LM's widths, decode and chunk M, bf16 and f32 compute, scaled or
+    not, ragged shapes, and a vmapped replica stack."""
+    kx, kw, ks = jax.random.split(jax.random.key(m * 7 + k + n), 3)
+    lead = (replicas,) if replicas > 1 else ()
+    x = _integer_x(kx, lead + (m, k), dtype)
+    wp = jax.random.bits(kw, lead + (-(-k // 32), n), jnp.uint32).astype(
+        jnp.int32)
+    s = (jax.random.uniform(ks, lead + (n,), minval=0.5, maxval=2.0)
+         if scaled else None)
+    want_fn = lambda x, wp, s: ref.binary_matmul_ref(  # noqa: E731
+        x, wp, s, compute_dtype=dtype)
+    if replicas > 1:
+        got = jax.vmap(lambda x, wp, s: ops.binary_matmul(x, wp, s))(x, wp, s)
+        want = jax.vmap(want_fn)(x, wp, s)
+    else:
+        got, want = ops.binary_matmul(x, wp, s), want_fn(x, wp, s)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_expand_pm1_is_unpack_bits(dtype):
+    """The kernel's weight stage, alone: its ±1 block, read in k_order
+    within each 256-row piece, is core.packing.unpack_bits bit for bit."""
+    words = jax.random.bits(jax.random.key(3), (24, 256), jnp.uint32).astype(
+        jnp.int32)
+    words = words.at[0, :4].set(jnp.array([0, -1, 1, -2 ** 31], jnp.int32))
+    got = jax.jit(BM.expand_pm1, static_argnums=1)(words, dtype)
+    assert got.dtype == dtype and got.shape == (768, 256)
+    order = BM.k_order(dtype)
+    assert sorted(order) == list(range(BM.PIECE))
+    want = P.unpack_bits(words, dtype).reshape(3, BM.PIECE, 256)[:, order]
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want).reshape(768, 256))
+    x = jnp.arange(2 * 768, dtype=jnp.float32).reshape(2, 768)
+    np.testing.assert_array_equal(
+        np.asarray(BM.permute_k(x, dtype)).reshape(2, 3, BM.PIECE),
+        np.asarray(x).reshape(2, 3, BM.PIECE)[:, :, order])
+
+
+def _pallas_grids(jaxpr):
+    """Grids of every pallas_call in a (closed) jaxpr, nested ones too."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            if isinstance(v, ClosedJaxpr):
+                grids += _pallas_grids(v.jaxpr)
+            elif isinstance(v, Jaxpr):
+                grids += _pallas_grids(v)
+    return grids
+
+
+def _grids(m, k, n, dtype=jnp.bfloat16, **blocks):
+    x = jax.ShapeDtypeStruct((m, k), dtype)
+    wp = jax.ShapeDtypeStruct((-(-k // 32), n), jnp.int32)
+    return _pallas_grids(jax.make_jaxpr(
+        lambda x, wp: ops.binary_matmul(x, wp, **blocks))(x, wp).jaxpr)
+
+
+@pytest.mark.parametrize("m", [1, 8, 16, 17, 32, 100, 256, 272, 500, 512])
+@pytest.mark.parametrize("k,n", LM_KN)
+def test_one_m_block_up_to_512_rows(m, k, n):
+    """Every M <= 512 runs one M-block, so each weight is expanded once per
+    call; the grid step expands at least 256K weights (block_k x block_n)."""
+    (grid,) = _grids(m, k, n)
+    assert grid[0] == 1
+    assert (k // grid[2]) * (n // grid[1]) >= 256 * 1024
+
+
+@pytest.mark.parametrize("m,k,n,kernel", [
+    (8, 512, 128, False),          # tiny
+    (16, 512, 1023, False),        # one MAC below 128 * 128 * 512 ...
+    (128, 512, 127, False),
+    (16, 512, 1024, True),         # ... and at it
+    (128, 512, 128, True),
+    (513, 512, 128, True),         # above 512 rows: M-blocks of 256
+    (1024, 3072, 3584, True),
+])
+def test_fallback_threshold_and_m_blocks(m, k, n, kernel):
+    """Below MIN_KERNEL_MACS (= 128 * 128 * 512, the parent's default
+    blocks) the jnp reference runs, at and above it the kernel, as before
+    blocks came from the shape; past 512 rows M splits into blocks of 256."""
+    assert ops.MIN_KERNEL_MACS == 128 * 128 * 512
+    grids = _grids(m, k, n)
+    assert len(grids) == int(kernel)
+    if kernel:
+        assert grids[0][0] == (1 if m <= 512 else -(-m // 256))
+
+
+def test_explicit_blocks_are_honoured():
+    (grid,) = _grids(256, 1024, 384, block_k=256)
+    assert grid[2] == 1024 // 256
+    (grid,) = _grids(256, 1024, 384, block_m=128, block_n=128)
+    assert grid[:2] == (2, 3)
 
 
 class TestBinarizePackKernel:

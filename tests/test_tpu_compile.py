@@ -64,13 +64,18 @@ def compile_text(one_chip, no_persistent_cache):
     return run
 
 
-@pytest.mark.parametrize("m", [8, 128], ids=["decode", "prefill"])
-@pytest.mark.parametrize("n", [QKV, D_FF])
-def test_binary_matmul(compile_text, m, n):
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("m", [16, 256], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n", [(D_MODEL, QKV), (D_MODEL, D_MODEL),
+                                 (D_MODEL, D_FF), (D_FF, D_MODEL)],
+                         ids=["qkv", "o", "up", "down"])
+def test_binary_matmul(compile_text, k, n, m, dtype):
+    """The blocks the wrapper chooses (one M-block, wide block_n/block_k)
+    and the integer expansion's bitcast, in bf16 and f32 compute."""
     text = compile_text(
-        lambda x, w, s: binary_matmul_pallas(x, w, s, block_m=m),
-        ((m, D_MODEL), jnp.bfloat16), ((D_MODEL // 32, n), jnp.int32),
-        ((n,), jnp.float32))
+        lambda x, w, s: binary_matmul_pallas(x, w, s, compute_dtype=dtype),
+        ((m, k), dtype), ((k // 32, n), jnp.int32), ((n,), jnp.float32))
     assert "tpu_custom_call" in text
 
 
